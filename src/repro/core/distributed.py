@@ -49,15 +49,16 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
-from repro.core.types import SolveResult, safe_inv, sweep_stop_flags
+from repro.core.types import (SolveResult, fp32_matmuls, safe_inv,
+                              sweep_stop_flags)
 
 
 def _psum(v, axes):
     return lax.psum(v, axes) if axes else v
 
 
+@fp32_matmuls
 def _bakp_local(x_loc, y_loc, a0_loc, atol_sse, rtol, *, nvars_loc: int,
                 thr: int, max_iter: int, omega: float, mode: str,
                 ridge: float, g_axes: Tuple[str, ...],
@@ -205,8 +206,8 @@ def _sharded_program(kind: str, mesh: Mesh, xshape: Tuple[int, int],
         def run(x_loc, y_loc, atol_sse, rtol):
             return _bakp_local(x_loc, y_loc, None, atol_sse, rtol, **kw)
         in_specs = (in_x, in_y, P(), P())
-    return jax.jit(shard_map(run, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False))
+    return jax.jit(jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 def _solve_sharded(kind, x, y, mesh, *, data_axes, model_axis, thr, max_iter,

@@ -47,7 +47,7 @@ from repro.core.solvebakf import solvebakf
 from repro.core.solvebakp import solvebakp
 from repro.core.spec import (_ITER_FIELDS, MethodEntry, SolverSpec,
                              register_method)
-from repro.core.types import SolveResult
+from repro.core.types import SolveResult, fp32_matmuls
 from repro.obs import record_dispatch
 
 _SHARDED_BACKENDS = {
@@ -287,7 +287,7 @@ def _stream_solve_method(p, y, spec: SolverSpec, *, a0=None, key=None,
     """Algorithm 2 with X streamed rather than VMEM-resident.
 
     Resident designs run the double-buffered Pallas kernel
-    (``repro.kernels.stream_solve``): x tiles live in ``pltpu.ANY`` (HBM)
+    (``repro.kernels.stream_solve``): x tiles live in ``pl.ANY`` (HBM)
     and DMA through a two-slot VMEM scratch while residual/coefficients
     stay on-chip, so the VMEM working set is two (block, obs) tiles
     regardless of vars.  Non-resident designs — store-backed handles whose
@@ -377,12 +377,14 @@ def _direct_result(x, y, coef, max_iter: int) -> SolveResult:
 
 
 @functools.partial(jax.jit, static_argnames=("max_iter",))
+@fp32_matmuls
 def _lstsq_kernel(x, y, max_iter: int) -> SolveResult:
     coef = jnp.linalg.lstsq(x, y.astype(jnp.float32))[0]
     return _direct_result(x, y, coef, max_iter)
 
 
 @functools.partial(jax.jit, static_argnames=("max_iter",))
+@fp32_matmuls
 def _normal_kernel(x, y, ridge, max_iter: int) -> SolveResult:
     g = x.T @ x + ridge * jnp.eye(x.shape[1], dtype=jnp.float32)
     coef = jax.scipy.linalg.cho_solve(
@@ -450,7 +452,7 @@ register_method(MethodEntry(
     blocked=True, streams=True, precisions=("fp32", "bf16"),
     lane="stream", prepare=_prep_stream, fallback="lstsq",
     summary="Algorithm 2 streaming out-of-core: x tiles double-buffered "
-            "from HBM (pltpu.ANY) through VMEM scratch, or fetched "
+            "from HBM (pl.ANY) through VMEM scratch, or fetched "
             "per-block through the design store's host/disk tiers for "
             "non-resident designs"))
 register_method(MethodEntry(

@@ -67,6 +67,22 @@ from repro.core.types import (SolveResult, column_norms_sq, safe_inv,
                               warm_retention_ok)
 
 
+def placement_sharding(placement, smesh):
+    """The ``NamedSharding`` a sharded placement's backend takes ``x`` in:
+    rows over the data axes (obs_sharded), replicated (rhs_sharded — the
+    devices share x), or rows × columns (mesh_2d)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    if placement.kind == "obs_sharded":
+        spec = P(smesh.data_axes, None)
+    elif placement.kind == "rhs_sharded":
+        spec = P(None, None)
+    elif placement.kind == "mesh_2d":
+        spec = P(smesh.data_axes, smesh.model_axis)
+    else:
+        raise ValueError(f"unknown placement kind {placement.kind!r}")
+    return NamedSharding(smesh.mesh, spec)
+
+
 def design_fingerprint(x, *, _prefix: str = "d") -> str:
     """Content fingerprint of a design matrix (shape + dtype + bytes).
 
@@ -281,21 +297,11 @@ class PreparedDesign:
         """
         if placement is None or not placement.sharded:
             return self.x_pad
-        from jax.sharding import NamedSharding, PartitionSpec as P
         with self._lock:
             if placement not in self._sharded:
-                if placement.kind == "obs_sharded":
-                    spec = P(smesh.data_axes, None)
-                elif placement.kind == "rhs_sharded":
-                    spec = P(None, None)  # replicated: devices share x
-                elif placement.kind == "mesh_2d":
-                    spec = P(smesh.data_axes, smesh.model_axis)
-                else:
-                    raise ValueError(
-                        f"unknown placement kind {placement.kind!r}")
                 self._sharded[placement] = jax.device_put(
                     self._require_x("x_for_placement"),
-                    NamedSharding(smesh.mesh, spec))
+                    placement_sharding(placement, smesh))
             return self._sharded[placement]
 
     def warm_method_state(self, spec: SolverSpec) -> None:
@@ -440,6 +446,7 @@ def prepare(
     *,
     fingerprint: Optional[str] = None,
     max_tenants: int = 64,
+    placement=None,
 ) -> PreparedDesign:
     """Build a ``PreparedDesign`` for ``x`` (see module doc).
 
@@ -455,8 +462,16 @@ def prepare(
         bytes); None defers to a lazy content hash on first
         ``design_key()`` access.
       max_tenants: LRU bound on retained per-tenant warm-start coefficients.
+      placement: a sharded placement (with ``mesh``) puts ``x`` straight
+        into that layout, so a design larger than one device's memory is
+        never gathered onto one device.
     """
-    x = jnp.asarray(x, jnp.float32)
+    sharded = placement is not None and placement.sharded
+    if sharded:
+        x = jax.device_put(np.asarray(x, np.float32),
+                           placement_sharding(placement, mesh))
+    else:
+        x = jnp.asarray(x, jnp.float32)
     if x.ndim != 2:
         raise ValueError(f"x must be 2D (obs, vars), got {x.shape}")
     if spec is not None:
@@ -470,6 +485,8 @@ def prepare(
         mesh=mesh,
         max_tenants=max_tenants,
     )
+    if sharded:
+        prepared._sharded[placement] = x
     if spec is not None:
         prepared.warm_method_state(spec)
     return prepared

@@ -30,9 +30,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core.types import (SolveResult, column_norms_sq, donate_default,
-                              safe_inv, sweep_stop_flags)
+                              fp32_matmuls, safe_inv, sweep_stop_flags)
 
 
+@fp32_matmuls
 def _solvebak_impl(
     x: jax.Array,
     y: jax.Array,
@@ -183,6 +184,7 @@ def solvebak(
     return fn(x, y, a0, cn, key, atol, rtol)
 
 
+@fp32_matmuls
 def solvebak_onesweep(x: jax.Array, y: jax.Array, a: jax.Array, e: jax.Array):
     """A single cyclic sweep (used by the Pallas-kernel reference tests).
 

@@ -28,12 +28,14 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core.solvebakp import solvebakp
-from repro.core.types import SelectResult, column_norms_sq, safe_inv
+from repro.core.types import (SelectResult, column_norms_sq, fp32_matmuls,
+                              safe_inv)
 
 
 @functools.partial(
     jax.jit, static_argnames=("max_feat", "refit_sweeps", "refit_thr")
 )
+@fp32_matmuls
 def solvebakf(
     x: jax.Array,
     y: jax.Array,

@@ -46,7 +46,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core.types import (SolveResult, column_norms_sq, donate_default,
-                              safe_inv, sweep_stop_flags)
+                              fp32_matmuls, safe_inv, sweep_stop_flags)
 
 
 def _pad_cols(x: jax.Array, thr: int):
@@ -60,6 +60,7 @@ def _pad_cols(x: jax.Array, thr: int):
     return x, mask, nblocks
 
 
+@fp32_matmuls
 def block_gram_cholesky(xb: jax.Array, ridge: float) -> jax.Array:
     """Cholesky factors of per-block Gram matrices.
 
@@ -77,6 +78,7 @@ def block_gram_cholesky(xb: jax.Array, ridge: float) -> jax.Array:
     return jax.vmap(lambda g: jax.scipy.linalg.cholesky(g, lower=True))(gram)
 
 
+@fp32_matmuls
 def _solvebakp_impl(
     x: jax.Array,
     y: jax.Array,

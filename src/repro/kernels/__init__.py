@@ -10,7 +10,7 @@
   block_update.py  obs-streamed rank-thr residual correction + fused
                    SolveBakF feature scoring.
   stream_solve.py  streaming out-of-core megakernel: x tiles stay in HBM
-                   (pltpu.ANY) and double-buffer through a two-slot VMEM
+                   (pl.ANY) and double-buffer through a two-slot VMEM
                    scratch per block — the VMEM working set is independent
                    of vars, so over-budget designs keep the single-launch
                    early-exit execution model.  Plus a host block loop
@@ -19,7 +19,7 @@
   ops.py           solver entries: solvebakp_kernel (fused when the design
                    fits VMEM, per-sweep launch loop otherwise),
                    solvebakp_stream_kernel (HBM-resident x, streamed) +
-                   wrappers (interpret=True off-TPU, y/a0 buffer donation
+                   wrappers (interpret mode on the CPU, y/a0 buffer donation
                    on accelerators).
   ref.py           pure-jnp oracles, tested via shape/dtype sweeps.
 """

@@ -20,6 +20,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.types import fp32_matmuls
+from repro.kernels.cd_sweep import (compiler_params, interpret_mode,
+                                    vmem_tile_bytes)
+
 
 def _block_update_kernel(x_ref, da_ref, e_ref, out_ref):
     """Grid: (n_obs_tiles,).  x_ref: (CB, OT); da_ref: (CB, k);
@@ -31,6 +35,7 @@ def _block_update_kernel(x_ref, da_ref, e_ref, out_ref):
     out_ref[...] = e_ref[...].astype(jnp.float32) - corr
 
 
+@fp32_matmuls
 def block_update(x_t_blk, e, da, *, obs_tile=4096, interpret=None):
     """e' = e − x_blkᵀ·da with obs streamed in ``obs_tile`` chunks.
 
@@ -41,8 +46,6 @@ def block_update(x_t_blk, e, da, *, obs_tile=4096, interpret=None):
     Returns:
       Updated residual, same rank as ``e``.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     cb, obs = x_t_blk.shape
     single = e.ndim == 1
     e2 = e.reshape(1, obs) if single else e
@@ -50,6 +53,10 @@ def block_update(x_t_blk, e, da, *, obs_tile=4096, interpret=None):
     obs_tile = min(obs_tile, obs)
     assert obs % obs_tile == 0, (obs, obs_tile)
     grid = (obs // obs_tile,)
+    # Double-buffered x/e/out tiles plus the resident increments.
+    vmem = 2 * (vmem_tile_bytes(cb, obs_tile, x_t_blk.dtype.itemsize)
+                + 2 * vmem_tile_bytes(nrhs, obs_tile, 4)
+                + vmem_tile_bytes(cb, nrhs, 4))
     out = pl.pallas_call(
         _block_update_kernel,
         grid=grid,
@@ -60,7 +67,8 @@ def block_update(x_t_blk, e, da, *, obs_tile=4096, interpret=None):
         ],
         out_specs=pl.BlockSpec((nrhs, obs_tile), lambda k: (0, k)),
         out_shape=jax.ShapeDtypeStruct((nrhs, obs), jnp.float32),
-        interpret=interpret,
+        compiler_params=compiler_params(vmem),
+        interpret=interpret_mode(interpret),
     )(x_t_blk, da.reshape(cb, nrhs).astype(jnp.float32),
       e2.astype(jnp.float32))
     return out[0] if single else out
@@ -87,6 +95,7 @@ def _score_kernel(x_ref, e_ref, invcn_ref, out_ref, g_scr):
         out_ref[...] = g * g * invcn_ref[...]
 
 
+@fp32_matmuls
 def score_features(x_t, e, inv_cn, *, col_block=512, obs_tile=4096,
                    interpret=None):
     """SolveBakF scores for all features: ⟨x_j,e⟩²/⟨x_j,x_j⟩, one x pass.
@@ -95,13 +104,15 @@ def score_features(x_t, e, inv_cn, *, col_block=512, obs_tile=4096,
       x_t: (vars, obs); e: (obs,); inv_cn: (vars,).
     Returns: (vars,) fp32 scores.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     nvars, obs = x_t.shape
     col_block = min(col_block, nvars)
     obs_tile = min(obs_tile, obs)
     assert nvars % col_block == 0 and obs % obs_tile == 0
     grid = (nvars // col_block, obs // obs_tile)
+    vmem = (2 * (vmem_tile_bytes(col_block, obs_tile, x_t.dtype.itemsize)
+                 + vmem_tile_bytes(1, obs_tile, 4)
+                 + 2 * vmem_tile_bytes(col_block, 1, 4))
+            + vmem_tile_bytes(col_block, 1, 4))
     out = pl.pallas_call(
         _score_kernel,
         grid=grid,
@@ -113,7 +124,8 @@ def score_features(x_t, e, inv_cn, *, col_block=512, obs_tile=4096,
         out_specs=pl.BlockSpec((col_block, 1), lambda i, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nvars, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((col_block, 1), jnp.float32)],
-        interpret=interpret,
+        compiler_params=compiler_params(vmem),
+        interpret=interpret_mode(interpret),
     )(x_t, e.reshape(1, obs).astype(jnp.float32),
       inv_cn.reshape(nvars, 1).astype(jnp.float32))
     return out[:, 0]

@@ -21,7 +21,7 @@ A mechanical port would round-trip the residual ``e`` through HBM per column
 HBM traffic per sweep:  vars·obs·dtype_bytes (reads) + O(vars+obs) —
 byte-optimal; arithmetic intensity ≈ 4 flops / dtype_bytes bytes, i.e. the
 algorithm is HBM-bandwidth-bound on TPU (819 GB/s v5e ⇒ roofline
-~1.6 Tflop/s effective in bf16).  See EXPERIMENTS.md §Roofline(solver).
+~1.6 Tflop/s effective in bf16).  See ``benchmarks/solver_roofline.py``.
 
 The dual kernel ``bakp_sweep_kernel`` is the SolveBakP (Algorithm 2) variant:
 identical memory schedule but MXU matvecs instead of the scalar loop.
@@ -29,6 +29,7 @@ identical memory schedule but MXU matvecs instead of the scalar loop.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -36,9 +37,45 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# TPU VMEM working-set budget the wrapper enforces (conservative v5e figure;
-# the compiler owns the real limit).
-VMEM_BUDGET_BYTES = 64 * 1024 * 1024
+# VMEM of one TPU v5e TensorCore: 128 MiB (Google Cloud, "TPU v5e").  The
+# compiler's default *scoped* limit is far below it (16 MiB), so every
+# pallas_call states its own limit (``compiler_params``).
+VMEM_CAPACITY_BYTES = 128 * 2**20
+# Room the compiler needs beyond the buffers the accounting functions count
+# (``fused_vmem_bytes``, ``stream_vmem_bytes``, ``sweep_vmem_bytes``):
+# the block update's per-chunk scratch (``OBS_CHUNK``), Mosaic's internal
+# scratch, semaphores and alignment padding.
+VMEM_SLACK_BYTES = 8 * 2**20
+# Working set a kernel may be dispatched with: capacity less slack.  The
+# compile rehearsal (tests/test_tpu_compile.py) pins this figure to the v5e
+# compiler — what the dispatch admits, the compiler accepts.
+VMEM_BUDGET_BYTES = VMEM_CAPACITY_BYTES - VMEM_SLACK_BYTES
+
+
+def vmem_tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of a (rows, cols) array as Mosaic lays it out: the lane
+    axis pads to 128 and the sublane axis to 8 rows of 32-bit words
+    (16 for 2-byte types; a single row is tiled (1, 128)) — so a (vars, 1)
+    column costs 128× its data."""
+    sub = 1 if rows == 1 else 8 * (4 // itemsize)
+    return (-(-rows // sub) * sub) * (-(-cols // 128) * 128) * itemsize
+
+
+def interpret_mode(interpret=None) -> bool:
+    """Whether a Pallas kernel runs in interpret mode: an explicit choice
+    wins; otherwise interpret exactly on the CPU backend, so an
+    accelerator never silently runs the Python interpreter."""
+    if interpret is not None:
+        return bool(interpret)
+    return jax.default_backend() == "cpu"
+
+
+def compiler_params(vmem_bytes: int) -> pltpu.CompilerParams:
+    """Mosaic parameters for a kernel whose accounted working set is
+    ``vmem_bytes``: the scoped VMEM limit covers it plus the compiler's
+    slack, capped at the chip's capacity."""
+    limit = min(int(vmem_bytes) + VMEM_SLACK_BYTES, VMEM_CAPACITY_BYTES)
+    return pltpu.CompilerParams(vmem_limit_bytes=limit)
 
 
 def bak_row_update(xj, inv_j, e):
@@ -46,7 +83,7 @@ def bak_row_update(xj, inv_j, e):
     per-sweep kernel below AND the fused megakernel — one definition so the
     two execution models cannot drift numerically).
 
-    Args: xj (1, obs) column; inv_j scalar 1/⟨x_j,x_j⟩; e (k, obs).
+    Args: xj (1, obs) column; inv_j (1, 1) 1/⟨x_j,x_j⟩; e (k, obs).
     Returns (da, e'): (1, k) increment and the corrected residual(s).
     """
     da = lax.dot_general(xj, e, (((1,), (1,)), ((), ())),
@@ -57,8 +94,45 @@ def bak_row_update(xj, inv_j, e):
     return da, e
 
 
+def bak_rows(x_ref, invcn_ref, row0, e_ref, on_da):
+    """Algorithm 1 over the rows of the (CB, obs) block ``x_ref``, in
+    order: each row's update reads the residual the previous one left in
+    ``e_ref``.  Row ``r`` of the block is design row ``row0 + r`` (``row0``
+    a multiple of the block's rows): its inverse norm is
+    ``invcn_ref[row0 + r]``, and ``on_da(row0 + r, da)`` stores its (1, k)
+    increment.
+
+    Rows are staged through an fp32 VMEM buffer one sublane-aligned group
+    at a time: Mosaic cannot slice an already-loaded value, and reads a
+    packed (bf16) ref only in whole row groups.
+    """
+    nrows = x_ref.shape[0]
+    grp = math.gcd(8 * (4 // x_ref.dtype.itemsize), nrows)
+
+    def staged(xg_ref):
+        def group(g, _):
+            r0 = pl.multiple_of(g * grp, grp)
+            xg_ref[...] = x_ref[pl.ds(r0, grp), :].astype(jnp.float32)
+            j0 = pl.multiple_of(row0 + r0, grp)
+
+            def row(t, _):
+                da, e = bak_row_update(xg_ref[pl.ds(t, 1), :],
+                                       invcn_ref[pl.ds(j0 + t, 1), :],
+                                       e_ref[...])
+                e_ref[...] = e
+                on_da(j0 + t, da)
+                return 0
+
+            return lax.fori_loop(0, grp, row, 0)
+
+        lax.fori_loop(0, nrows // grp, group, 0)
+
+    pl.run_scoped(staged, pltpu.VMEM((grp, x_ref.shape[1]), jnp.float32))
+
+
 def bakp_block_update(xb, inv, e, omega):
-    """One Algorithm-2 block update on loaded values (shared as above).
+    """One Algorithm-2 block update on loaded values (the host block loop,
+    ``stream_solve_blocks``; the kernels run ``bakp_block_update_ref``).
 
     Args: xb (CB, obs) block; inv (CB, 1); e (k, obs); omega relaxation.
     Returns (da, e'): (CB, k) increments and the rank-CB-corrected
@@ -70,6 +144,53 @@ def bakp_block_update(xb, inv, e, omega):
     e = e - lax.dot_general(da, xb, (((0,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
     return da, e
+
+
+# Lanes of obs one block update touches at a time in the kernels.  Dots of
+# fp32 operands run at HIGHEST precision (``core.types.fp32_matmuls``: the
+# default rounds them to one bfloat16 pass), and Mosaic's six-pass split
+# holds several copies of its operands in VMEM — chunking obs bounds that
+# scratch by the chunk, not the (block, obs) tile.
+OBS_CHUNK = 2048
+
+
+def bakp_block_update_ref(x_ref, inv, e_ref, omega):
+    """``bakp_block_update`` on refs, inside a kernel: ``x_ref`` is the
+    (CB, obs) block, ``e_ref`` the (k, obs) residual, updated in place.
+    obs is swept in ``OBS_CHUNK``-lane chunks twice — inner products, then
+    the residual correction.  Returns da (CB, k).
+
+    Every kernel hands in the block as a whole ref (a grid tile, a scratch
+    slot, or a leading-axis view of a (nblocks, CB, obs) design), never a
+    row slice of a larger one: the CPU backend compiles a matvec on a
+    sliced operand with another summation order, and the execution models
+    would then drift apart in the last bit in interpret mode.
+    """
+    obs = e_ref.shape[1]
+    chunk = OBS_CHUNK if obs % OBS_CHUNK == 0 else obs
+
+    def cols(c):
+        return pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+
+    def inner(c, g):
+        xb = x_ref[:, cols(c)].astype(jnp.float32)
+        return g + lax.dot_general(xb, e_ref[:, cols(c)],
+                                   (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    g = lax.fori_loop(0, obs // chunk, inner,
+                      jnp.zeros((inv.shape[0], e_ref.shape[0]), jnp.float32))
+    da = omega * g * inv
+
+    def correct(c, _):
+        xb = x_ref[:, cols(c)].astype(jnp.float32)
+        e_ref[:, cols(c)] = e_ref[:, cols(c)] - lax.dot_general(
+            da, xb, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return 0
+
+    lax.fori_loop(0, obs // chunk, correct, 0)
+    return da
 
 
 def _cd_sweep_kernel(x_ref, invcn_ref, e_in_ref, da_ref, e_out_ref, e_scr):
@@ -85,20 +206,10 @@ def _cd_sweep_kernel(x_ref, invcn_ref, e_in_ref, da_ref, e_out_ref, e_scr):
     def _init():
         e_scr[...] = e_in_ref[...].astype(jnp.float32)
 
-    xb = x_ref[...].astype(jnp.float32)      # (CB, obs)
-    inv = invcn_ref[...]                     # (CB, 1)
-    cb = xb.shape[0]
-    nrhs = da_ref.shape[1]
+    def store_da(r, da):
+        da_ref[pl.ds(r, 1), :] = da
 
-    def body(t, _):
-        xj = lax.dynamic_slice_in_dim(xb, t, 1, axis=0)       # (1, obs)
-        inv_j = lax.dynamic_slice_in_dim(inv, t, 1, 0)[0, 0]
-        da, e = bak_row_update(xj, inv_j, e_scr[...])
-        e_scr[...] = e
-        pl.store(da_ref, (pl.dslice(t, 1), pl.dslice(0, nrhs)), da)
-        return 0
-
-    lax.fori_loop(0, cb, body, 0)
+    bak_rows(x_ref, invcn_ref, 0, e_scr, store_da)
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _flush():
@@ -116,15 +227,23 @@ def _bakp_sweep_kernel(omega, x_ref, invcn_ref, e_in_ref, da_ref, e_out_ref,
     def _init():
         e_scr[...] = e_in_ref[...].astype(jnp.float32)
 
-    xb = x_ref[...].astype(jnp.float32)          # (CB, obs)
-    inv = invcn_ref[...]                         # (CB, 1)
-    da, e = bakp_block_update(xb, inv, e_scr[...], omega)
-    e_scr[...] = e
-    da_ref[...] = da
+    da_ref[...] = bakp_block_update_ref(x_ref, invcn_ref[...], e_scr, omega)
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _flush():
         e_out_ref[...] = e_scr[...]
+
+
+def sweep_vmem_bytes(obs: int, nrhs: int, itemsize: int, *,
+                     block: int) -> int:
+    """VMEM working set of one per-sweep launch (bytes): the grid
+    double-buffers the (block, obs) x tile and the (block, 1) norms, and
+    the (k, obs) residual is held three times — input, output and the
+    resident fp32 scratch."""
+    return (2 * vmem_tile_bytes(block, obs, itemsize)
+            + 2 * vmem_tile_bytes(block, 1, 4)
+            + 3 * vmem_tile_bytes(nrhs, obs, 4)
+            + 2 * vmem_tile_bytes(block, nrhs, 4))
 
 
 def _sweep_call(kernel_fn, x_t, e, inv_cn, *, block, interpret):
@@ -134,7 +253,7 @@ def _sweep_call(kernel_fn, x_t, e, inv_cn, *, block, interpret):
     e2 = e.reshape(1, obs) if single else e          # (k, obs) kernel layout
     nrhs = e2.shape[0]
     nblocks = nvars // block
-    vmem = nrhs * obs * 4 + block * obs * x_t.dtype.itemsize
+    vmem = sweep_vmem_bytes(obs, nrhs, x_t.dtype.itemsize, block=block)
     if vmem > VMEM_BUDGET_BYTES:
         raise ValueError(
             f"cd_sweep working set {vmem/2**20:.1f} MiB exceeds VMEM budget; "
@@ -159,6 +278,7 @@ def _sweep_call(kernel_fn, x_t, e, inv_cn, *, block, interpret):
             jax.ShapeDtypeStruct((nrhs, obs), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((nrhs, obs), jnp.float32)],
+        compiler_params=compiler_params(vmem),
         interpret=interpret,
     )(x_t, inv_cn.reshape(nvars, 1).astype(jnp.float32),
       e2.astype(jnp.float32))
@@ -176,20 +296,17 @@ def cd_sweep(x_t, e, inv_cn, *, block=256, interpret=None):
         single HBM stream of x (multi-RHS serving path).
       inv_cn: (vars,) inverse squared column norms.
       block: rows of x_t staged to VMEM per grid step (multiple of 8).
-      interpret: force interpret mode (defaults to True off-TPU).
+      interpret: force interpret mode (default: ``interpret_mode``).
     Returns:
       (da, e'): increments and post-sweep residual — (vars,)/(obs,) for 1D
       input, (vars, k)/(k, obs) for multi-RHS.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return _sweep_call(_cd_sweep_kernel, x_t, e, inv_cn, block=block,
-                       interpret=interpret)
+                       interpret=interpret_mode(interpret))
 
 
 def bakp_sweep(x_t, e, inv_cn, *, block=256, omega=1.0, interpret=None):
     """One SolveBakP (block-Jacobi) sweep; multi-RHS as ``cd_sweep``."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return _sweep_call(functools.partial(_bakp_sweep_kernel, omega),
-                       x_t, e, inv_cn, block=block, interpret=interpret)
+                       x_t, e, inv_cn, block=block,
+                       interpret=interpret_mode(interpret))

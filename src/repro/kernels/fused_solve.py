@@ -41,8 +41,8 @@ dispatch on ``fused_fits`` and fall back to the per-sweep stream or the XLA
 solvers when the design is too large (``repro.core.methods`` wires exactly
 that for the ``"bakp_fused"``/``"bak_fused"`` registry entries).
 
-Off TPU the kernel runs in interpret mode — numerically identical, used by
-the test suite and the CI benchmarks.
+On the CPU backend the kernel runs in interpret mode (the same math, in
+float32 on the host), used by the test suite and the CI benchmarks.
 """
 from __future__ import annotations
 
@@ -57,7 +57,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.types import (SolveResult, column_norms_sq_t, donate_default,
-                              safe_inv, sweep_stop_flags)
+                              fp32_matmuls, safe_inv, sweep_stop_flags)
 
 # The VMEM budget is shared with the per-sweep path.  Import the module via
 # importlib: the package re-exports a *function* named cd_sweep, which
@@ -67,16 +67,19 @@ _cd = importlib.import_module("repro.kernels.cd_sweep")
 
 def fused_vmem_bytes(nvars: int, obs: int, nrhs: int, itemsize: int,
                      *, max_iter: int = 1) -> int:
-    """VMEM working set of one fused solve (bytes).
-
-    x resident (nvars·obs·itemsize) + residual in/out (2·k·obs·4) +
-    a0/coef (2·nvars·k·4) + inv_cn (nvars·4) + history (max_iter·4).
+    """VMEM working set of one fused solve (bytes), in Mosaic's padded
+    layout (``cd_sweep.vmem_tile_bytes``): x resident (nvars, obs) +
+    residual in/out (2 × (k, obs) fp32) + a0/coef (2 × (nvars, k)) +
+    inv_cn (nvars, 1) + history (max_iter, 1).  The block update's
+    per-chunk scratch and Algorithm 1's row staging buffer are inside
+    ``cd_sweep.VMEM_SLACK_BYTES``.
     """
-    return (nvars * obs * itemsize
-            + 2 * nrhs * obs * 4
-            + 2 * nvars * nrhs * 4
-            + nvars * 4
-            + max_iter * 4)
+    tile = _cd.vmem_tile_bytes
+    return (tile(nvars, obs, itemsize)
+            + 2 * tile(nrhs, obs, 4)
+            + 2 * tile(nvars, nrhs, 4)
+            + tile(nvars, 1, 4)
+            + tile(max_iter, 1, 4))
 
 
 def fused_fits(nvars: int, obs: int, nrhs: int, itemsize: int,
@@ -98,7 +101,9 @@ def _fused_kernel(scal_ref, x_ref, invcn_ref, e0_ref, a0_ref,
 
     scal_ref: (3,) SMEM — [atol_sse, rtol, omega] (traced solver knobs,
         scalar-memory so tolerance changes never recompile).
-    x_ref: (nvars, obs) VMEM — the resident design, transposed layout.
+    x_ref: (nblocks, block, obs) VMEM — the resident design, transposed
+        layout, one leading index per column block (a free reshape of
+        (nvars, obs)).
     invcn_ref: (nvars, 1) VMEM — inverse squared column norms (0 for
         zero/padded columns, so their updates are pinned to 0).
     e0_ref: (k, obs) / a0_ref: (nvars, k) VMEM — initial residual(s) and
@@ -110,8 +115,7 @@ def _fused_kernel(scal_ref, x_ref, invcn_ref, e0_ref, a0_ref,
     as while(sweeps) × fori(blocks) so convergence aborts it outright.
     """
     atol_sse, rtol, omega = scal_ref[0], scal_ref[1], scal_ref[2]
-    nvars = x_ref.shape[0]
-    nblocks = nvars // block
+    nblocks = x_ref.shape[0]
 
     e_ref[...] = e0_ref[...].astype(jnp.float32)
     coef_ref[...] = a0_ref[...]
@@ -129,43 +133,28 @@ def _fused_kernel(scal_ref, x_ref, invcn_ref, e0_ref, a0_ref,
     sse0 = _sse()
 
     def block_step(b, _):
-        xb = pl.load(x_ref, (pl.dslice(b * block, block),
-                             slice(None))).astype(jnp.float32)   # (CB, obs)
-        inv = pl.load(invcn_ref, (pl.dslice(b * block, block),
-                                  slice(None)))                  # (CB, 1)
         # Block math shared with the per-sweep kernels (cd_sweep.py) — one
         # definition keeps the two execution models numerically in lockstep
         # (the n_sweeps/history parity tests depend on it).
         if variant == "bak":
             # Algorithm 1: strictly sequential per column within the block.
-            def row(t, _):
-                xj = lax.dynamic_slice_in_dim(xb, t, 1, axis=0)  # (1, obs)
-                inv_j = lax.dynamic_slice_in_dim(inv, t, 1, 0)[0, 0]
-                da, e = _cd.bak_row_update(xj, inv_j, e_ref[...])
-                e_ref[...] = e
-                old = pl.load(coef_ref, (pl.dslice(b * block + t, 1),
-                                         slice(None)))
-                pl.store(coef_ref, (pl.dslice(b * block + t, 1),
-                                    slice(None)), old + da)
-                return 0
+            def add_da(r, da):
+                coef_ref[pl.ds(r, 1), :] = coef_ref[pl.ds(r, 1), :] + da
 
-            lax.fori_loop(0, block, row, 0)
+            _cd.bak_rows(x_ref.at[b], invcn_ref, b * block, e_ref, add_da)
         else:
             # Algorithm 2: Jacobi within the block, both matvecs on the MXU.
-            da, e = _cd.bakp_block_update(xb, inv, e_ref[...], omega)
-            e_ref[...] = e
-            old = pl.load(coef_ref, (pl.dslice(b * block, block),
-                                     slice(None)))
-            pl.store(coef_ref, (pl.dslice(b * block, block),
-                                slice(None)), old + da)
+            rows = pl.ds(pl.multiple_of(b * block, block), block)
+            da = _cd.bakp_block_update_ref(x_ref.at[b], invcn_ref[rows, :],
+                                           e_ref, omega)
+            coef_ref[rows, :] = coef_ref[rows, :] + da
         return 0
 
     def sweep_body(state):
         i, sse_prev, converged, stop = state
         lax.fori_loop(0, nblocks, block_step, 0)
         sse = _sse()
-        pl.store(hist_ref, (pl.dslice(i, 1), pl.dslice(0, 1)),
-                 sse.reshape(1, 1))
+        hist_ref[pl.ds(i, 1), :] = sse.reshape(1, 1)
         # The shared stopping criterion, evaluated on-chip — scalar jnp ops
         # trace fine inside the kernel, so the fused path can never drift
         # from the host solvers' semantics.
@@ -189,6 +178,7 @@ def _fused_call(x_t, inv_cn, e0, a0m, scal, *, block, max_iter, variant,
                 interpret):
     nvars, obs = x_t.shape
     nrhs = e0.shape[0]
+    itemsize = x_t.dtype.itemsize
     kern = functools.partial(_fused_kernel, block=block, max_iter=max_iter,
                              variant=variant)
     return pl.pallas_call(
@@ -217,13 +207,15 @@ def _fused_call(x_t, inv_cn, e0, a0m, scal, *, block, max_iter, variant,
             jax.ShapeDtypeStruct((1, 1), jnp.int32),            # converged
         ],
         cost_estimate=pl.CostEstimate(
-            flops=4.0 * max_iter * nvars * obs * nrhs,
-            bytes_accessed=nvars * obs * x_t.dtype.itemsize
+            flops=4 * max_iter * nvars * obs * nrhs,
+            bytes_accessed=nvars * obs * itemsize
             + 2 * nrhs * obs * 4 + 2 * nvars * nrhs * 4,
             transcendentals=0,
         ),
+        compiler_params=_cd.compiler_params(fused_vmem_bytes(
+            nvars, obs, nrhs, itemsize, max_iter=max_iter)),
         interpret=interpret,
-    )(scal, x_t, inv_cn, e0, a0m)
+    )(scal, x_t.reshape(nvars // block, block, obs), inv_cn, e0, a0m)
 
 
 def validate_solver_args(x_t, y, cn, inv_cn, a0):
@@ -272,6 +264,7 @@ def solve_init(x_t, y, inv_cn, a0, multi):
     return inv_cn, a0m, e0
 
 
+@fp32_matmuls
 def _fused_impl(x_t, y, inv_cn, a0, atol, rtol, omega, *, block, max_iter,
                 variant, multi, interpret):
     nvars, obs = x_t.shape
@@ -331,7 +324,7 @@ def fused_solve(
       block / max_iter / atol / rtol / omega: as ``solvebakp_kernel``.
       variant: "bakp" (Algorithm 2, MXU) or "bak" (Algorithm 1,
         bit-faithful sequential order).
-      interpret: force interpret mode (defaults to True off-TPU).
+      interpret: force interpret mode (default: ``cd_sweep.interpret_mode``).
       donate: donate the ``y``/``a0`` buffers to the solve (cuts
         steady-state HBM allocation on the serving flush path).  Default:
         auto-donate only host (numpy) operands, on accelerator backends at
@@ -361,8 +354,6 @@ def fused_solve(
             f"per-sweep stream (solvebakp_persweep_kernel), shard obs "
             f"across devices (repro.core.distributed), or reduce "
             f"obs ({obs}) / vars ({nvars}) / nrhs ({nrhs}).")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    fn = _jitted(block, max_iter, variant, multi, bool(interpret),
-                 donate_default(donate, y, a0))
+    fn = _jitted(block, max_iter, variant, multi,
+                 _cd.interpret_mode(interpret), donate_default(donate, y, a0))
     return fn(x_t, y, inv_cn, a0, atol, rtol, omega)

@@ -18,8 +18,9 @@ every batch) gets its steady-state HBM allocation cut.  ``jax.Array``
 operands are never auto-donated (callers may reuse them); ``donate=True``
 forces it, ``donate=False`` disables it.
 
-Off TPU all kernels run in interpret mode (Python execution of the kernel
-body) — numerically identical, used by the test suite.
+On the CPU backend all kernels run in interpret mode (Python execution of
+the kernel body; ``cd_sweep.interpret_mode``) — numerically identical,
+used by the test suite.
 """
 from __future__ import annotations
 
@@ -31,15 +32,16 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core.types import (SolveResult, column_norms_sq_t, donate_default,
-                              safe_inv, sweep_stop_flags)
+                              fp32_matmuls, safe_inv, sweep_stop_flags)
 from repro.obs import record_dispatch
 from repro.kernels.block_update import block_update, score_features
-from repro.kernels.cd_sweep import bakp_sweep, cd_sweep
+from repro.kernels.cd_sweep import bakp_sweep, cd_sweep, interpret_mode
 from repro.kernels.fused_solve import (fused_fits, fused_solve, solve_init,
                                        validate_solver_args)
 from repro.kernels.stream_solve import stream_fits, stream_solve
 
 
+@fp32_matmuls
 def _persweep_impl(x_t, y, inv_cn, a0, atol, rtol, *, block, max_iter,
                    variant, multi, interpret, omega):
     # omega is compile-time here: the sweep kernels close over it (a traced
@@ -108,10 +110,9 @@ def solvebakp_persweep_kernel(
     model — kept as the large-design fallback and benchmark baseline; see
     module doc).  Arguments as ``solvebakp_kernel``.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     multi, _, inv_cn = validate_solver_args(x_t, y, cn, inv_cn, a0)
-    fn = _jitted_persweep(block, max_iter, variant, multi, bool(interpret),
+    fn = _jitted_persweep(block, max_iter, variant, multi,
+                          interpret_mode(interpret),
                           donate_default(donate, y, a0), float(omega))
     return fn(x_t, y, inv_cn, a0, atol, rtol)
 
@@ -192,7 +193,7 @@ def solvebakp_stream_kernel(
     interpret: Optional[bool] = None,
     donate: Optional[bool] = None,
 ) -> SolveResult:
-    """Streaming out-of-core SolveBakP: x stays in HBM (``pltpu.ANY``) and
+    """Streaming out-of-core SolveBakP: x stays in HBM (``pl.ANY``) and
     tiles double-buffer through a two-slot VMEM scratch while the residual,
     coefficients and convergence state stay on-chip for every sweep
     (``repro.kernels.stream_solve``).  The VMEM working set is two

@@ -1,4 +1,4 @@
-"""Streaming out-of-core SolveBakP Pallas kernel — x tiles in ``pltpu.ANY``
+"""Streaming out-of-core SolveBakP Pallas kernel — x tiles in ``pl.ANY``
 memory, double-buffered into VMEM scratch.
 
 The fused megakernel (``repro.kernels.fused_solve``) keeps the whole design
@@ -7,7 +7,7 @@ budget.  This module generalises the same in-kernel (sweeps × col-blocks)
 iteration space to designs that only fit **HBM** (or, via the design
 store's host fallback below, not even that):
 
-  * ``x_t`` stays in ``pltpu.ANY`` memory (the compiler leaves it in HBM);
+  * ``x_t`` stays in ``pl.ANY`` memory (the compiler leaves it in HBM);
     only a ``(2, block, obs)`` double buffer of it lives in VMEM scratch.
   * Each column block is DMA'd in with ``pltpu.make_async_copy`` one block
     ahead of the compute (slot ``b % 2`` computes while slot ``(b+1) % 2``
@@ -23,22 +23,23 @@ store's host fallback below, not even that):
 
 x crosses HBM once per *sweep* here (vs once per *solve* fused) — the
 price of unbounded design size; the block math itself is the shared
-``cd_sweep.bakp_block_update``, so the two execution models cannot drift
-numerically (the ``bakp_stream`` parity tests pin this).
+``cd_sweep.bakp_block_update_ref``, so the two execution models cannot
+drift numerically (the ``bakp_stream`` parity tests pin this).
 
 ``stream_solve_blocks`` is the out-of-core endpoint: a host-side
 per-block sweep loop over any object exposing the ``StoreBlockSource``
 interface (``shape``, ``num_blocks(thr)``, ``block_t(thr, j)``), used for
 designs whose bytes live on the host/disk tiers of ``repro.store`` — and,
-off-TPU, as the interpret-friendly reference the parity suite runs
+on the CPU, as the interpret-friendly reference the parity suite runs
 everywhere.  It uses the same shared block update and stopping criterion.
 
-Off TPU the Pallas kernel runs in interpret mode (DMA semantics included),
-numerically identical to the compiled path.
+On the CPU backend the Pallas kernel runs in interpret mode (DMA semantics
+included): the same math as the compiled path.
 """
 from __future__ import annotations
 
 import functools
+import importlib
 from typing import Optional
 
 import jax
@@ -48,11 +49,11 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.types import SolveResult, donate_default, sweep_stop_flags
+from repro.core.types import (SolveResult, donate_default, fp32_matmuls,
+                              sweep_stop_flags)
 from repro.kernels.fused_solve import solve_init, validate_solver_args
 
 # Shared block math + VMEM budget (see fused_solve on the importlib note).
-import importlib
 _cd = importlib.import_module("repro.kernels.cd_sweep")
 
 
@@ -64,14 +65,16 @@ def stream_x_resident_bytes(block: int, obs: int, itemsize: int) -> int:
 
 def stream_vmem_bytes(nvars: int, obs: int, nrhs: int, itemsize: int, *,
                       block: int, max_iter: int = 1) -> int:
-    """VMEM working set of one streaming solve (bytes): the x double
-    buffer + residual in/out (2·k·obs·4) + a0/coef (2·nvars·k·4) + inv_cn
-    (nvars·4) + history."""
-    return (stream_x_resident_bytes(block, obs, itemsize)
-            + 2 * nrhs * obs * 4
-            + 2 * nvars * nrhs * 4
-            + nvars * 4
-            + max_iter * 4)
+    """VMEM working set of one streaming solve (bytes), in Mosaic's padded
+    layout: the x double buffer + residual in/out (2 × (k, obs) fp32) +
+    a0/coef (2 × (nvars, k)) + inv_cn (nvars, 1) + history.  The block
+    update's per-chunk scratch is inside ``cd_sweep.VMEM_SLACK_BYTES``."""
+    tile = _cd.vmem_tile_bytes
+    return (2 * tile(block, obs, itemsize)
+            + 2 * tile(nrhs, obs, 4)
+            + 2 * tile(nvars, nrhs, 4)
+            + tile(nvars, 1, 4)
+            + tile(max_iter, 1, 4))
 
 
 def stream_fits(nvars: int, obs: int, nrhs: int, itemsize: int, *,
@@ -88,7 +91,7 @@ def _stream_kernel(scal_ref, x_hbm_ref, invcn_ref, e0_ref, a0_ref,
                    coef_ref, e_ref, hist_ref, sse_ref, n_ref, conv_ref,
                    *, block, max_iter):
     """Streaming whole-solve kernel body.  Refs as ``_fused_kernel`` except
-    ``x_hbm_ref`` lives in ``pltpu.ANY`` (HBM) — the kernel DMAs one
+    ``x_hbm_ref`` lives in ``pl.ANY`` (HBM) — the kernel DMAs one
     (block, obs) tile ahead of the compute into VMEM scratch."""
     atol_sse, rtol, omega = scal_ref[0], scal_ref[1], scal_ref[2]
     nvars, obs_p = x_hbm_ref.shape
@@ -123,15 +126,10 @@ def _stream_kernel(scal_ref, x_hbm_ref, invcn_ref, e0_ref, a0_ref,
                 dma((b + 1) % 2, b + 1).start()
 
             dma(b % 2, b).wait()
-            xb = xscr_ref[b % 2].astype(jnp.float32)       # (block, obs)
-            inv = pl.load(invcn_ref, (pl.dslice(b * block, block),
-                                      slice(None)))        # (block, 1)
-            da, e = _cd.bakp_block_update(xb, inv, e_ref[...], omega)
-            e_ref[...] = e
-            old = pl.load(coef_ref, (pl.dslice(b * block, block),
-                                     slice(None)))
-            pl.store(coef_ref, (pl.dslice(b * block, block),
-                                slice(None)), old + da)
+            rows = pl.ds(pl.multiple_of(b * block, block), block)
+            da = _cd.bakp_block_update_ref(xscr_ref.at[b % 2],
+                                           invcn_ref[rows, :], e_ref, omega)
+            coef_ref[rows, :] = coef_ref[rows, :] + da
             return 0
 
         def sweep_body(state):
@@ -139,8 +137,7 @@ def _stream_kernel(scal_ref, x_hbm_ref, invcn_ref, e0_ref, a0_ref,
             dma(0, 0).start()                              # warm-up fetch
             lax.fori_loop(0, nblocks, block_step, 0)
             sse = _sse()
-            pl.store(hist_ref, (pl.dslice(i, 1), pl.dslice(0, 1)),
-                     sse.reshape(1, 1))
+            hist_ref[pl.ds(i, 1), :] = sse.reshape(1, 1)
             converged, stop = sweep_stop_flags(sse, sse_prev, sse0,
                                                atol_sse, rtol)
             return i + 1, sse, converged, stop
@@ -164,12 +161,13 @@ def _stream_kernel(scal_ref, x_hbm_ref, invcn_ref, e0_ref, a0_ref,
 def _stream_call(x_t, inv_cn, e0, a0m, scal, *, block, max_iter, interpret):
     nvars, obs_p = x_t.shape
     nrhs = e0.shape[0]
+    itemsize = x_t.dtype.itemsize
     kern = functools.partial(_stream_kernel, block=block, max_iter=max_iter)
     return pl.pallas_call(
         kern,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),     # x stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),        # x stays in HBM
             pl.BlockSpec(memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pltpu.VMEM),
@@ -192,15 +190,18 @@ def _stream_call(x_t, inv_cn, e0, a0m, scal, *, block, max_iter, interpret):
         ],
         cost_estimate=pl.CostEstimate(
             # x crosses HBM once per sweep here (vs once per solve fused).
-            flops=4.0 * max_iter * nvars * obs_p * nrhs,
-            bytes_accessed=max_iter * nvars * obs_p * x_t.dtype.itemsize
+            flops=4 * max_iter * nvars * obs_p * nrhs,
+            bytes_accessed=max_iter * nvars * obs_p * itemsize
             + 2 * nrhs * obs_p * 4 + 2 * nvars * nrhs * 4,
             transcendentals=0,
         ),
+        compiler_params=_cd.compiler_params(stream_vmem_bytes(
+            nvars, obs_p, nrhs, itemsize, block=block, max_iter=max_iter)),
         interpret=interpret,
     )(scal, x_t, inv_cn, e0, a0m)
 
 
+@fp32_matmuls
 def _stream_impl(x_t, y, inv_cn, a0, atol, rtol, omega, *, block, max_iter,
                  multi, interpret):
     nvars, obs_p = x_t.shape
@@ -265,13 +266,12 @@ def stream_solve(
             f"exceed the VMEM budget "
             f"({_cd.VMEM_BUDGET_BYTES / 2**20:.0f} MiB); reduce block "
             f"({block}) / nrhs ({nrhs}), or use the per-sweep stream")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    fn = _jitted(block, max_iter, multi, bool(interpret),
+    fn = _jitted(block, max_iter, multi, _cd.interpret_mode(interpret),
                  donate_default(donate, y, a0))
     return fn(x_t, y, inv_cn, a0, atol, rtol, omega)
 
 
+@fp32_matmuls
 def stream_solve_blocks(
     blocks,
     y,

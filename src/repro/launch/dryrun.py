@@ -190,6 +190,9 @@ def main():
                     help="JSON dict of ModelConfig overrides (perf levers)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     overrides = json.loads(args.override) if args.override else None
     res = run_cell(args.arch, args.cell, multi_pod=args.multi_pod,
                    overrides=overrides, fsdp=not args.no_fsdp,

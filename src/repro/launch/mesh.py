@@ -4,32 +4,25 @@ Functions (not module-level constants) so importing never touches jax device
 state.  Single pod: (16, 16) = 256 v5e chips, axes (data, model).
 Multi-pod: (2, 16, 16) = 512 chips, axes (pod, data, model); the pod axis
 composes with data for batch/FSDP sharding (repro.distributed.sharding).
-
-``AxisType`` (explicit-sharding axis modes) only exists on newer jax; on
-jax 0.4.x the plain ``jax.make_mesh`` call is equivalent for everything this
-repo does (shard_map with explicit specs), so the builders degrade
-gracefully instead of Importing-Error the whole distributed test suite.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def _make_mesh(shape, axes):
-    try:
-        from jax.sharding import AxisType
-    except ImportError:  # jax < 0.5: no axis_types kwarg
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(AxisType.Auto,) * len(axes))
+def make_mesh(shape, axes):
+    """A device mesh whose axes are all ``Auto`` (shard_map with explicit
+    specs everywhere, no explicit-sharding types)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for host-device tests (requires matching device count)."""
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)

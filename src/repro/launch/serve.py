@@ -23,6 +23,9 @@ def main():
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.configs.registry import get
     from repro.launch.steps import make_decode_step, make_prefill_step
     from repro.models.kvcache import init_cache

@@ -42,8 +42,11 @@ def main():
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.core.distributed import (solvebakp_2d, solvebakp_obs_sharded)
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.hlo_analysis import analyze
     from repro.launch.mesh import make_production_mesh
+
+    enable_compile_cache()
 
     mesh = make_production_mesh(multi_pod=args.multi_pod)
     data_axes = tuple(a for a in mesh.axis_names if a != "model")

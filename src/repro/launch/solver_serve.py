@@ -308,6 +308,8 @@ def main():
     if args.mesh:
         ensure_mesh_devices(args.mesh)  # must precede any jax import
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.core import method_names
     from repro.serve import (PlacementPolicy, ServeConfig, SolverServeEngine,
                              build_serve_mesh)

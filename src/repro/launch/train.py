@@ -32,6 +32,9 @@ def main():
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.configs.registry import get
     from repro.data import SyntheticLM
     from repro.distributed.fault_tolerance import (CheckpointManager,

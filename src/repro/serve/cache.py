@@ -219,8 +219,12 @@ class DesignCache:
             entry = self.get(key, record_stats)
             hit = entry is not None
             if not hit:
+                # A sharded placement builds x in its layout directly: a
+                # design one device cannot hold never lands on device 0.
                 built = prepare(np.asarray(build_x_pad(), np.float32),
-                                fingerprint=key, max_tenants=self.max_tenants)
+                                mesh=mesh, fingerprint=key,
+                                max_tenants=self.max_tenants,
+                                placement=placement)
                 entry = self.put(key, built)
         if spec is not None:
             entry.warm_lane_state(spec, placement=placement, mesh=mesh)

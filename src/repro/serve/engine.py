@@ -852,9 +852,12 @@ class SolverServeEngine:
                 if nxt.method != cur.method:
                     cur_place = None  # fallback may not be shardable
             retries += 1
+            to = self._rung_label(nxt, cur_a0 is not None)
             self._m_retries.inc(1, reason=reason, from_path=frm,
-                                to_path=self._rung_label(
-                                    nxt, cur_a0 is not None))
+                                to_path=to)
+            # The counter has no room for the cause; the log keeps it.
+            _log.warning("solve on %s failed (%s: %s); retrying on %s", frm,
+                         reason, exc if exc is not None else "diverged", to)
             with self._stats_lock:
                 self.stats.retries += 1
             delay = ladder.backoff_s(retries - 1, cfg.retry_backoff_s)

@@ -155,17 +155,16 @@ def build_serve_mesh(spec: str) -> ServeMesh:
     *before* importing jax — ``repro.launch.solver_serve --mesh`` does this
     for you).
     """
-    # Shares the jax-version compat shim with the production mesh builders
-    # (imported lazily: building a mesh is the first jax touch here).
-    from repro.launch.mesh import _make_mesh
+    # Imported lazily: building a mesh is the first jax touch here.
+    from repro.launch.mesh import make_mesh
 
     parts = [int(p) for p in spec.lower().split("x")]
     if not parts or any(p < 1 for p in parts) or len(parts) > 2:
         raise ValueError(f"mesh spec must be 'D' or 'DxM', got {spec!r}")
     if len(parts) == 1 or parts[1] == 1:
-        mesh = _make_mesh((parts[0],), ("data",))
+        mesh = make_mesh((parts[0],), ("data",))
         return ServeMesh(mesh=mesh, data_axes=("data",), model_axis=None)
-    mesh = _make_mesh(tuple(parts), ("data", "model"))
+    mesh = make_mesh(tuple(parts), ("data", "model"))
     return ServeMesh(mesh=mesh, data_axes=("data",), model_axis="model")
 
 

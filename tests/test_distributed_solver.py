@@ -80,8 +80,15 @@ SCRIPT = textwrap.dedent("""
                                  max_iter=20, mode="gram")
     np.testing.assert_allclose(np.array(rrhs.coef), np.array(ref.coef),
                                rtol=1e-5, atol=1e-6)
+    # The histories agree to rtol while the SSE carries signal.  Once the
+    # solve reaches the fp32 floor, each residual entry is rounding noise of
+    # size ~eps·|y|, so the SSE there is ~|Y|²·eps² whatever the reduction
+    # order: that is the absolute agreement fp32 can give.
+    floor = float(np.sum(Y.astype(np.float64) ** 2)) \\
+        * float(np.finfo(np.float32).eps) ** 2
     np.testing.assert_allclose(np.array(rrhs.history)[:20],
-                               np.array(ref.history)[:20], rtol=1e-4)
+                               np.array(ref.history)[:20], rtol=1e-4,
+                               atol=floor)
 
     # warm start from the exact solution: first-sweep residual ~ 0
     for fn, kw in ((solvebakp_obs_sharded, {}),
@@ -240,8 +247,7 @@ class TestRhsShardedApi:
 
 
 def test_mesh_builder_no_axistype_needed():
-    """make_debug_mesh must work on jax versions without sharding.AxisType
-    (the root cause of the seed's distributed-test failure)."""
+    """make_debug_mesh builds a mesh over the visible devices."""
     m = make_debug_mesh((1,), ("data",))
     assert m.shape["data"] == 1
     assert jax.devices()[0] in list(m.devices.flat)

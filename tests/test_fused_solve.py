@@ -14,12 +14,14 @@ fall back to the XLA path and still serve the request.
 """
 import sys
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import SolverSpec, prepare, solve, solvebak, solvebakp
 from repro.core.spec import solver_method
+from repro.core.types import donate_default
 from repro.kernels import (fused_fits, fused_solve, fused_vmem_bytes,
                            solvebakp_kernel, solvebakp_persweep_kernel)
 
@@ -140,18 +142,25 @@ class TestVmemBudget:
             fused_solve(jnp.asarray(x.T), jnp.asarray(y), block=8)
 
     def test_budget_accounting(self):
+        # Mosaic's padded layout: lanes pad to 128, sublanes to 8 rows, so
+        # the (k=2, obs) residuals take 8 rows and the (vars, k) / (vars, 1)
+        # / (50, 1) columns take 128 lanes.  x (128, 1024) + residual in
+        # and out + a0/coef + inv_cn + history.
         b = fused_vmem_bytes(128, 1024, 2, 4, max_iter=50)
-        assert b == (128 * 1024 * 4 + 2 * 2 * 1024 * 4 + 2 * 128 * 2 * 4
-                     + 128 * 4 + 50 * 4)
+        assert b == (128 * 1024 * 4 + 2 * 8 * 1024 * 4 + 2 * 128 * 128 * 4
+                     + 128 * 128 * 4 + 56 * 128 * 4)
         assert fused_fits(128, 1024, 2, 4, max_iter=50)
+        # A bf16 x halves only the resident design (16-row sublane tiles).
+        assert (fused_vmem_bytes(128, 1024, 2, 2, max_iter=50)
+                == b - 128 * 1024 * 2)
 
     def test_kernel_shim_falls_back_to_persweep(self, rng, monkeypatch):
         """Over budget, solvebakp_kernel silently uses the per-sweep loop
         (whose own smaller working set still fits) instead of raising."""
         x, _, y = _system(rng, obs=128, nvars=16)
-        # fused needs the whole x resident; the per-sweep loop only one
-        # (block, obs) tile + the residual.
-        monkeypatch.setattr(_CD, "VMEM_BUDGET_BYTES", 6 * 1024)
+        # fused needs the whole x resident (~50 KiB padded); the per-sweep
+        # loop only two (block, obs) tiles + the residual (~26 KiB).
+        monkeypatch.setattr(_CD, "VMEM_BUDGET_BYTES", 32 * 1024)
         r = solvebakp_kernel(jnp.asarray(x.T), jnp.asarray(y), block=8,
                              max_iter=30)
         ref = solvebakp(jnp.asarray(x), jnp.asarray(y), thr=8, max_iter=30)
@@ -285,3 +294,16 @@ class TestValidationAndDonation:
                                    atol=1e-4)
         np.testing.assert_array_equal(np.asarray(r1.coef),
                                       np.asarray(r4.coef))
+
+    def test_donate_default_on_an_accelerator(self, monkeypatch):
+        """The accelerator branch of the donation default (never taken on
+        the CPU backend): host operands donate at top level only, device
+        arrays never."""
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        host = np.zeros(4, np.float32)
+        assert donate_default(None, host, None)
+        assert not donate_default(None, jnp.asarray(host))
+        assert not donate_default(False, host)
+        inside = []
+        jax.jit(lambda v: inside.append(donate_default(None, host)) or v)(1.0)
+        assert inside == [False]

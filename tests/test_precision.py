@@ -22,6 +22,7 @@ from repro import obs
 from repro.core import (PRECISIONS, SolverSpec, UnsupportedSpecError,
                         methods_for_precision, prepare)
 from repro.core.types import column_norms_sq, column_norms_sq_t
+from repro.kernels.cd_sweep import sweep_vmem_bytes
 from repro.kernels.fused_solve import fused_vmem_bytes
 
 _CD = importlib.import_module("repro.kernels.cd_sweep")
@@ -134,7 +135,7 @@ class TestDispatchPaths:
         design = prepare(x, spec)
         r32 = design.solve(y)
         need16 = fused_vmem_bytes(64, 512, 1, 2, max_iter=120)
-        sweep16 = 512 * 4 + 16 * 512 * 2  # persweep tile working set
+        sweep16 = sweep_vmem_bytes(512, 1, 2, block=16)
         assert sweep16 < need16
         monkeypatch.setattr(_CD, "VMEM_BUDGET_BYTES",
                             (sweep16 + need16) // 2)
