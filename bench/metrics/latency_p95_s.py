@@ -1,0 +1,8 @@
+"""95th-percentile latency, over the same requests and clock as
+``latency_p50_s``."""
+import numpy as np
+
+
+def read(run):
+    lat = [r.latency_s for r in run.records if r.latency_s is not None]
+    return float(np.percentile(lat, 95)) if lat else None
