@@ -301,8 +301,9 @@ def main():
                          "(0 = ephemeral; the resolved port is printed)")
     ap.add_argument("--trace-dir", default=None, metavar="DIR",
                     help="capture a jax profiler trace of the run into DIR "
-                         "(view in TensorBoard/Perfetto; flushes and solver "
-                         "calls appear as named obs.profile_region blocks)")
+                         "(view in TensorBoard/Perfetto; every obs.span - "
+                         "dispatcher waits, flushes, batch build, solver "
+                         "calls, result fetch - appears by name)")
     args = ap.parse_args()
 
     if args.mesh:

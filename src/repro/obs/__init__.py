@@ -1,6 +1,6 @@
 """repro.obs — telemetry for the solver-serving stack.
 
-Three pieces, all stdlib-only at import time (jax is touched lazily and
+Four pieces, all stdlib-only at import time (jax is touched lazily and
 only by the profiler hooks):
 
   metrics.py    Counter / Gauge / Histogram (fixed log-spaced buckets) in a
@@ -8,14 +8,16 @@ only by the profiler hooks):
                 dict, ``render_prometheus()`` → text exposition format.
   trace.py      ``now()`` — THE serving clock (``time.perf_counter``;
                 queue-wait and solve-time compose because every component
-                reads the same clock); ``span()`` context-manager tracing
-                into a ring buffer + optional JSONL sink; ``SolveTelemetry``
-                per-request records; the kernel-path relay
-                (``record_dispatch``/``consume_dispatch``) that lets the
-                engine report which dispatch route a solve *actually* took.
-  profiling.py  Opt-in ``profile_region()``/``start_profiling()`` wrapping
-                ``jax.profiler`` so flushes and fused-kernel launches show
-                up named in TensorBoard/Perfetto traces.
+                reads the same clock); ``span()`` — the one span API: a
+                ring buffer + optional JSONL sink, and, while a profiler
+                trace is open, a ``TraceAnnotation`` of the same name on
+                the device trace; ``SolveTelemetry`` per-request records;
+                the kernel-path relay (``record_dispatch``/
+                ``consume_dispatch``) that lets the engine report which
+                dispatch route a solve *actually* took.
+  profiling.py  Opt-in ``start_profiling()``/``stop_profiling()`` around
+                ``jax.profiler``'s trace; while it is open ``span()``
+                annotates it.
   export.py     ``write_metrics_json`` and the stdlib-``http.server``
                 Prometheus scrape endpoint (``start_metrics_server``).
 
@@ -24,8 +26,7 @@ call; ``set_enabled`` flips it at runtime for A/B overhead runs).
 
 The serving stack (``repro.serve``), the kernel dispatch shims
 (``repro.kernels.ops``, ``repro.core.methods``) and the launch drivers all
-record here; ``benchmarks/serve_obs.py`` gates the overhead and snapshots
-the registry into ``BENCH_obs.json`` in CI.
+record here; ``bench/`` reads the telemetry and the spans on the chip.
 """
 from repro.obs.export import (MetricsServer, start_metrics_server,
                               write_metrics_json)
@@ -33,8 +34,8 @@ from repro.obs.metrics import (COUNT_BUCKETS, LATENCY_BUCKETS, Counter,
                                Gauge, Histogram, MetricsRegistry,
                                default_registry, enabled, log_buckets,
                                set_enabled)
-from repro.obs.profiling import (profile_region, profiling_active,
-                                 start_profiling, stop_profiling)
+from repro.obs.profiling import (profiling_active, start_profiling,
+                                 stop_profiling)
 from repro.obs.trace import (SolveTelemetry, SpanRecord, Tracer,
                              consume_dispatch, get_tracer, now,
                              record_dispatch, span)
@@ -56,7 +57,6 @@ __all__ = [
     "get_tracer",
     "log_buckets",
     "now",
-    "profile_region",
     "profiling_active",
     "record_dispatch",
     "set_enabled",

@@ -11,9 +11,12 @@ absolute deadlines live on one timeline.
 **Spans.** ``Tracer.span("engine.flush", bucket=..., method=...)`` is a
 context manager recording wall time, nesting (per-thread stack → parent
 name + depth) and free-form tags into an in-memory ring buffer, with an
-optional JSONL sink for offline analysis.  Spans are for *structure* (what
-called what, where the time went inside one flush); the aggregate story
-lives in the metrics registry.
+optional JSONL sink for offline analysis.  While a profiler trace is open
+(``repro.obs.profiling.start_profiling``) the same call also enters a
+``jax.profiler.TraceAnnotation`` of the span's name, so the span lands on
+the device trace's timeline too: one call per site, both records.  Spans
+are for *structure* (what called what, where the time went inside one
+flush); the aggregate story lives in the metrics registry.
 
 **SolveTelemetry.** One record per served request — who (tenant), where
 (bucket, kernel path, placement), how (warm/cold, batch kind/size), and
@@ -33,6 +36,7 @@ from dataclasses import asdict, dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
 from repro.obs import metrics as _metrics
+from repro.obs import profiling as _profiling
 
 #: The single serving clock (seconds, monotonic, highest resolution
 #: available).  Compare/subtract only against other ``now()`` readings.
@@ -105,15 +109,25 @@ class Tracer:
 
     # ------------------------------------------------------------ record
     @contextmanager
-    def span(self, name: str, **tags):
+    def span(self, name: str, *, defer: Optional[list] = None, **tags):
         """Record one span; yields the (mutable) ``SpanRecord`` so the body
-        can attach result tags.  No-op (yields None) when obs is disabled."""
+        can attach result tags.  While a profiler trace is open the span is
+        also a ``TraceAnnotation`` of ``name`` (the tags stay in the ring).
+        No-op (yields None) when obs is disabled.
+
+        ``defer``: a list the finished record is appended to instead of
+        the ring and sink; the caller hands it to ``commit`` later, e.g.
+        once it has released a lock the span closed under, so the sink's
+        file write never runs under that lock."""
         if not _metrics.enabled():
             yield None
             return
         stack: List[SpanRecord] = getattr(self._local, "stack", None)
         if stack is None:
             stack = self._local.stack = []
+        ann = _profiling.annotation(name)
+        if ann is not None:
+            ann.__enter__()
         rec = SpanRecord(
             name=name, t_start=now(),
             tags={k: _jsonable(v) for k, v in tags.items()},
@@ -124,13 +138,24 @@ class Tracer:
             yield rec
         finally:
             rec.t_end = now()
+            if ann is not None:
+                ann.__exit__(None, None, None)
             stack.pop()
-            with self._lock:
+            if defer is not None:
+                defer.append(rec)
+            else:
+                self.commit([rec])
+
+    def commit(self, recs: List[SpanRecord]) -> None:
+        """Write finished span records to the ring and the sink."""
+        with self._lock:
+            for rec in recs:
                 self._ring.append(rec)
                 if self._sink is not None:
                     json.dump(rec.as_dict(), self._sink)
                     self._sink.write("\n")
-                    self._sink.flush()
+            if recs and self._sink is not None:
+                self._sink.flush()
 
     # ------------------------------------------------------------- reads
     def spans(self, name: Optional[str] = None) -> List[SpanRecord]:
@@ -154,9 +179,9 @@ def get_tracer() -> Tracer:
     return _tracer
 
 
-def span(name: str, **tags):
+def span(name: str, *, defer: Optional[list] = None, **tags):
     """``get_tracer().span(...)`` — the standard instrumentation call."""
-    return _tracer.span(name, **tags)
+    return _tracer.span(name, defer=defer, **tags)
 
 
 # -------------------------------------------------------- kernel-path relay
@@ -207,10 +232,23 @@ class SolveTelemetry:
     (e.g. a ``bakp_fused`` request whose coalesced width outgrew VMEM and
     re-routed to XLA), which ``method`` alone cannot show.
 
-    ``queue_wait_s`` (submit → batch fire) and ``deadline_margin_s``
-    (deadline − completion; negative = missed) are dispatcher-side and stay
-    None on the synchronous engine path.  All timestamps/durations are on
-    the ``obs.now()`` clock.
+    ``queue_wait_s`` (submit → batch fire), ``lane_wait_s`` (the fired
+    batch queued on its execution lane → the lane starting it) and
+    ``deadline_margin_s`` (deadline − completion; negative = missed) are
+    dispatcher-side and stay None on the synchronous engine path.
+
+    The engine's host work around the solve, per solve unit (a coalesced
+    group or vmapped stack shares its unit's): ``build_s`` (the flush's
+    grouping and design lookups plus the unit's padding of y and a0, up to
+    the solver call: the ``engine.build`` spans), ``solve_s`` (the solver
+    call to its result being ready on the device, retries included),
+    ``fetch_s`` (ready result → finished ``ServedSolve``: the divergence
+    check's host reads, the coefficient and residual copies, SSE and
+    telemetry: the ``engine.fetch`` spans).  Unset (None) on error
+    results.  All
+    timestamps/durations are on the ``obs.now()`` clock, so a request's
+    ``queue_wait_s + lane_wait_s + build_s + solve_s + fetch_s`` fits in
+    its ticket's latency.
 
     ``retries`` counts the retry-ladder steps the request's solve took
     (``repro.resilience``): 0 = first attempt succeeded; the ``method``/
@@ -236,7 +274,10 @@ class SolveTelemetry:
     converged: bool = False
     retries: int = 0
     solve_s: float = 0.0
+    build_s: Optional[float] = None
+    fetch_s: Optional[float] = None
     queue_wait_s: Optional[float] = None
+    lane_wait_s: Optional[float] = None
     deadline_margin_s: Optional[float] = None
     error_type: Optional[str] = None
 

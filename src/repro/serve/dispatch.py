@@ -34,6 +34,15 @@ computed from the most urgent pending deadline/idle expiry (no fixed-rate
 polling): it wakes exactly when the next batch could fire, or immediately
 on submit()/drain()/stop().
 
+**Spans** (``repro.obs.span``; on the device trace while profiling):
+``dispatch.hold`` — the dispatch thread's wait while a batch is pending
+(its idle-timeout or deadline-margin hold); ``dispatch.idle`` — its wait
+with no request in the system; ``dispatch.admit`` — admitting arrivals and
+firing ready batches; ``dispatch.solve_batch`` — a fired batch's work on
+its lane thread, from the engine call to its tickets completed.  The wait
+while only lanes work has no span, so the lanes' own spans name the
+device's idle time then.
+
 **Backpressure** — at most ``max_queue`` requests may be incomplete
 (queued + pending + solving) at once.  ``backpressure="reject"`` makes
 ``submit`` raise ``QueueFullError`` immediately; ``"block"`` makes it wait
@@ -56,6 +65,7 @@ Example::
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 from collections import deque
 from dataclasses import dataclass, field
@@ -168,6 +178,8 @@ class SolveTicket:
         self.submitted_at = obs.now()
         self.fired_at: Optional[float] = None
         self.completed_at: Optional[float] = None
+        self.lane_wait_s: Optional[float] = None  # fired batch queued on
+        # its lane -> the lane starting it (stamped by the lane work)
         self.deadline_met: Optional[bool] = None
         self._event = threading.Event()
         self._result: Optional[ServedSolve] = None
@@ -252,8 +264,10 @@ class SolveTicket:
         if tel is not None:
             # Back-fill the async-path timings the engine can't see: how
             # long the request waited in the dispatcher before its batch
-            # fired, and how much deadline headroom was left at completion.
+            # fired and then on its lane, and how much deadline headroom
+            # was left at completion.
             tel.queue_wait_s = self.queue_wait_s
+            tel.lane_wait_s = self.lane_wait_s
             if self.deadline is not None:
                 tel.deadline_margin_s = self.deadline - self.completed_at
         self._event.set()
@@ -518,19 +532,24 @@ class AsyncDispatcher:
         return max(0.0, t - obs.now())
 
     def _dispatch_loop(self) -> None:
+        waited: list = []  # the wait's span record, closed under _cv
         while True:
             with self._cv:
                 if not self._intake and not self._stopping:
                     # Sleep exactly until the most urgent pending batch's
                     # deadline-margin/idle expiry; fully idle we sleep
                     # until submit()/drain()/stop() notifies (no polling).
-                    self._cv.wait(self._next_wake_delay())
+                    with self._wait_span(waited):
+                        self._cv.wait(self._next_wake_delay())
                 arrivals = []
                 while self._intake:
                     arrivals.append(self._intake.popleft())
                 stopping = self._stopping
                 draining = self._draining
                 abandon = self._abandon
+            if waited:  # ring and sink written outside the lock
+                obs.get_tracer().commit(waited)
+                waited.clear()
             if stopping and abandon:
                 residual = [t for t in arrivals if not t._cancelled]
                 residual += [t for b in self._pending.values()
@@ -541,15 +560,30 @@ class AsyncDispatcher:
                 if residual:
                     self._on_complete(residual)
                 return  # stop() finalizes fired-but-unserved lane works
-            for ticket in arrivals:
-                self._admit(ticket)
-            now = obs.now()
-            for lane, urgency, chunk in self._fire_ready(
-                    now, drain_all=draining or stopping):
-                self._submit_batch(lane, urgency, chunk)
+            if arrivals or self._pending:
+                with obs.span("dispatch.admit", arrivals=len(arrivals)):
+                    for ticket in arrivals:
+                        self._admit(ticket)
+                    for lane, urgency, chunk in self._fire_ready(
+                            obs.now(), drain_all=draining or stopping):
+                        self._submit_batch(lane, urgency, chunk)
             if stopping and not self._pending:
                 self._drain_works()
                 return
+
+    def _wait_span(self, defer: list):
+        """The span over one wait of the dispatch thread (under ``_cv``):
+        ``dispatch.hold`` while a batch is pending, ``dispatch.idle`` while
+        no request is in the system, none while only lanes work (the
+        device trace names an idle gap by the latest-started span open
+        over it, which then has to be the lane's).  Its record goes to
+        ``defer``, for the loop to commit once ``_cv`` is released."""
+        if self._pending:
+            return obs.span("dispatch.hold", defer=defer,
+                            batches=len(self._pending))
+        if self._inflight == 0:
+            return obs.span("dispatch.idle", defer=defer)
+        return contextlib.nullcontext()
 
     def _admit(self, ticket: SolveTicket) -> None:
         """Normalise + fingerprint one request and join it to its batch.
@@ -681,22 +715,27 @@ class AsyncDispatcher:
                 return True
 
         def run() -> None:
+            lane_wait = obs.now() - work.enqueued_at
             if not try_claim():
                 return
             if self._abandon:
                 for t in tickets:
                     t._fail(DispatcherStopped("dispatcher stopped"))
             else:
-                try:
-                    with obs.span("dispatch.solve_batch", size=len(tickets),
-                                  lane=lane.label):
+                # Ends before _on_complete: dispatch.idle can open only
+                # once no batch is solving.
+                with obs.span("dispatch.solve_batch", size=len(tickets),
+                              lane=lane.label):
+                    for t in tickets:
+                        t.lane_wait_s = lane_wait
+                    try:
                         served = self.engine.serve(
                             [t.request for t in tickets])
-                    for ticket, result in zip(tickets, served):
-                        ticket._complete(result)
-                except Exception as exc:  # engine failure: fail the batch
-                    for ticket in tickets:
-                        ticket._fail(exc)
+                        for ticket, result in zip(tickets, served):
+                            ticket._complete(result)
+                    except Exception as exc:  # engine failure: fail batch
+                        for ticket in tickets:
+                            ticket._fail(exc)
             self._on_complete(tickets)
             with self._works_lock:
                 self._works.pop(work, None)

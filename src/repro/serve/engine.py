@@ -51,6 +51,14 @@ small number of compiled batch solves:
 Results come back as per-request ``ServedSolve``s, in submission order, with
 padding stripped and per-request SSE recomputed from the stripped residual.
 
+Spans (``repro.obs.span``; on the device trace while profiling):
+``engine.flush`` around a whole flush; ``engine.build`` around the host
+work before a solver call (the flush's grouping and design lookups, then
+each unit's padding of y and a0); ``solve/<method>`` (``solve/vmap/<method>``)
+around the solver launch; ``engine.fetch`` from the result being ready on
+the device to the finished ``ServedSolve``.  Each request's telemetry
+carries its unit's ``build_s``, ``solve_s`` and ``fetch_s``.
+
 Flushing is exception-safe: a batch whose solver raises is isolated — every
 request in it gets an error result (``ServedSolve.error`` set, zero
 coefficients) and the remaining batches still run, so one poisoned request
@@ -66,6 +74,7 @@ Example::
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import itertools
 import logging
@@ -101,6 +110,42 @@ _log = logging.getLogger(__name__)
 # instead of a resident X copy that could never be admitted.
 _STREAM_REROUTE = frozenset(
     {"bak", "bakp", "bakp_gram", "bakp_fused", "bak_fused"})
+
+
+class _HostTime:
+    """The host time one solve unit spends around its solver call, for its
+    requests' telemetry: wall seconds under its ``engine.build`` and
+    ``engine.fetch`` spans."""
+
+    __slots__ = ("build_s", "fetch_s")
+
+    def __init__(self, build_s: float = 0.0):
+        self.build_s = build_s
+        self.fetch_s = 0.0
+
+    def unit(self) -> "_HostTime":
+        """A unit's account, starting from its flush's build."""
+        return _HostTime(self.build_s)
+
+    def build(self):
+        return self._phase("engine.build", "build_s")
+
+    def fetch(self):
+        return self._phase("engine.fetch", "fetch_s")
+
+    @contextlib.contextmanager
+    def _phase(self, name: str, field: str):
+        with obs.span(name) as rec:
+            yield
+        if rec is not None:
+            setattr(self, field, getattr(self, field) + rec.duration_s)
+
+    def stamp(self, served: Sequence[ServedSolve]) -> None:
+        """Write the unit's times into each result's telemetry."""
+        for r in served:
+            if r.telemetry is not None:
+                r.telemetry.build_s = self.build_s
+                r.telemetry.fetch_s = self.fetch_s
 
 
 @dataclass
@@ -320,9 +365,8 @@ class SolverServeEngine:
             buckets=obs.COUNT_BUCKETS)
         # Bound-series children for the hot label combos: the per-request
         # and per-solve record sites run on the flush path, and rebuilding
-        # a sorted label key every call is measurable there (the serve_obs
-        # overhead gate holds this under 5%).  Only a handful of combos
-        # exist, so the caches stay tiny.
+        # a sorted label key every call is measurable there.  Only a
+        # handful of combos exist, so the caches stay tiny.
         self._c_served: dict = {}
         self._c_sweeps: dict = {}
         self._c_solve: dict = {}
@@ -475,20 +519,30 @@ class SolverServeEngine:
         with self._stats_lock:
             self.stats.requests += len(requests)
         self._m_requests.inc(len(requests))
-        with obs.span("engine.flush", requests=len(requests)), \
-                obs.profile_region("engine.flush"):
+        with obs.span("engine.flush", requests=len(requests)):
             return self._flush(requests)
 
     def _flush(self, requests: List[SolveRequest]) -> List[ServedSolve]:
         """Pure batch-builder: grouping, design-cache lookups and lane
-        routing happen here on the calling thread; the actual solves are
-        work units submitted to the engine's lane pool (``_run_units``), so
-        batches bound for different lanes (single-device xla/fused vs each
-        mesh placement) overlap instead of serialising."""
+        routing happen here on the calling thread (``engine.build``); the
+        actual solves are work units submitted to the engine's lane pool
+        (``_run_units``), so batches bound for different lanes
+        (single-device xla/fused vs each mesh placement) overlap instead of
+        serialising."""
         results: List[Optional[ServedSolve]] = [None] * len(requests)
-        # (lane, size, run, fail_idxs, bucket) — the last two let
-        # _run_units fail a unit's unanswered requests when the unit never
-        # ran to completion (lane worker-thread death / shutdown).
+        flush_time = _HostTime()
+        with flush_time.build():
+            units = self._build_units(requests, results, flush_time)
+        self._run_units(units, requests, results)
+        assert all(r is not None for r in results)
+        return results
+
+    def _build_units(self, requests, results, flush_time):
+        """Group ``requests`` and resolve their design entries into solve
+        units for ``_run_units``: (lane, size, run, fail_idxs, bucket) —
+        the last two let ``_run_units`` fail a unit's unanswered requests
+        when the unit never ran to completion (lane worker-thread death /
+        shutdown)."""
         units: List[Tuple[LaneKey, int, object, List[int], tuple]] = []
         cfg = self.config
 
@@ -534,7 +588,8 @@ class SolverServeEngine:
                          idxs, bucket, len(idxs),
                          functools.partial(self._solve_multi_rhs, requests,
                                            idxs, entry, hit, bucket,
-                                           results, gplacement, key))
+                                           results, gplacement, key,
+                                           flush_time))
                 else:
                     singles.extend((i, entry, hit, key) for i in idxs)
             # vmap batching is single-device only (a vmapped shard_map would
@@ -553,7 +608,7 @@ class SolverServeEngine:
                              len(chunk),
                              functools.partial(self._solve_vmapped,
                                                requests, chunk, bucket,
-                                               results))
+                                               results, flush_time))
                     else:
                         idx, entry, hit, key = chunk[0]
                         unit(self.lanes.lane_for(method, placement,
@@ -561,17 +616,16 @@ class SolverServeEngine:
                              [idx], bucket, 1,
                              functools.partial(self._solve_one, requests,
                                                idx, entry, hit, bucket,
-                                               results, placement, key))
+                                               results, placement, key,
+                                               flush_time))
             else:
                 for idx, entry, hit, key in singles:
                     unit(self.lanes.lane_for(method, placement, self.mesh),
                          [idx], bucket, 1,
                          functools.partial(self._solve_one, requests, idx,
                                            entry, hit, bucket, results,
-                                           placement, key))
-        self._run_units(units, requests, results)
-        assert all(r is not None for r in results)
-        return results
+                                           placement, key, flush_time))
+        return units
 
     def _run_units(self, units, requests, results) -> None:
         """Execute flush work units on their lanes and wait for all.
@@ -709,7 +763,7 @@ class SolverServeEngine:
         eff = spec.replace(atol=atol)
         if placement is not None and placement.kind == "mesh_2d":
             eff = eff.replace(omega=self.config.omega_2d)
-        with obs.profile_region(f"solve/{eff.method}"):
+        with obs.span(f"solve/{eff.method}"):
             return entry.solve(y_dev, a0, spec=eff, placement=placement,
                                mesh=self.mesh)
 
@@ -768,7 +822,8 @@ class SolverServeEngine:
         return True
 
     def _attempt_solve(self, spec: SolverSpec, entry, y, atol: float, a0,
-                       placement, *, deadline_at: Optional[float] = None,
+                       placement, host: _HostTime, *,
+                       deadline_at: Optional[float] = None,
                        rebuild=None, sse0: Optional[float] = None,
                        need_multi: bool = False):
         """One solve with the retry/degradation ladder wrapped around it.
@@ -794,6 +849,9 @@ class SolverServeEngine:
         the last diverged result returns as-is (flagged so ``_strip``
         skips warm retention).
 
+        Each attempt's divergence check reads the result back to the host,
+        so it counts as fetch time (``host``), not solve time.
+
         Returns ``(res, spec, entry, placement, retries, diverged,
         a0_used)`` — the rung that finally served, so the caller records
         the method/path that actually ran.
@@ -811,10 +869,11 @@ class SolverServeEngine:
                 jax.block_until_ready(res.coef)
             except Exception as e:
                 exc = e
-            forced = (exc is None
-                      and faults.hit("solver.diverge", cur.method)
-                      is not None)
-            diverged = forced or (exc is None and self._diverged(res, sse0))
+            with host.fetch():
+                forced = (exc is None
+                          and faults.hit("solver.diverge", cur.method)
+                          is not None)
+                diverged = forced or (exc is None and self._diverged(res, sse0))
             if exc is None and not diverged:
                 return (res, cur, cur_entry, cur_place, retries, False,
                         cur_a0)
@@ -967,7 +1026,7 @@ class SolverServeEngine:
         )
 
     def _solve_multi_rhs(self, requests, idxs, entry, hit, bucket, results,
-                         placement=None, key=None):
+                         placement=None, key=None, flush_time=None):
         """Coalesce same-design requests into one (obs, k_pad) solve.
 
         Warm and cold members coalesce: if any member warm-starts, the
@@ -980,33 +1039,35 @@ class SolverServeEngine:
         lane is chosen — except that the retry ladder drops it when a
         fallback rung changes the method (see ``_attempt_solve``).
         """
+        host = (flush_time or _HostTime()).unit()
         obs_p, vars_p = bucket
         k = len(idxs)
         k_pad = next_pow2(k)
         req0 = requests[idxs[0]]
-        spec = self.spec_for(req0)
-        mentry = solver_method(spec.method)
-        ys = np.zeros((obs_p, k_pad), np.float32)
-        sse0 = 0.0
-        for c, idx in enumerate(idxs):
-            y = np.asarray(requests[idx].y, np.float32)
-            ys[: y.shape[0], c] = y
-            sse0 += float(np.dot(y, y))
-        if mentry.iterative:
-            a0s = [self._resolve_a0(requests[idx], entry) for idx in idxs]
-        else:  # direct methods don't iterate, so warm starts are meaningless
-            a0s = [None] * k
-        a0_mat = None
-        if any(a is not None for a in a0s):
-            a0_mat = np.zeros((vars_p, k_pad), np.float32)
-            for c, a in enumerate(a0s):
-                if a is not None:
-                    a0_mat[:, c] = self._pad_a0(a, vars_p)
-        # Same design => same real obs for every member of the group.
-        obs_real = np.asarray(req0.x).shape[0]
-        atol = self._padded_atol(spec.atol, obs_real * k, obs_p * k_pad)
-        deadlines = [requests[i].deadline_at for i in idxs
-                     if requests[i].deadline_at is not None]
+        with host.build():
+            spec = self.spec_for(req0)
+            mentry = solver_method(spec.method)
+            ys = np.zeros((obs_p, k_pad), np.float32)
+            sse0 = 0.0
+            for c, idx in enumerate(idxs):
+                y = np.asarray(requests[idx].y, np.float32)
+                ys[: y.shape[0], c] = y
+                sse0 += float(np.dot(y, y))
+            if mentry.iterative:
+                a0s = [self._resolve_a0(requests[idx], entry) for idx in idxs]
+            else:  # direct methods don't iterate, so warm starts are meaningless
+                a0s = [None] * k
+            a0_mat = None
+            if any(a is not None for a in a0s):
+                a0_mat = np.zeros((vars_p, k_pad), np.float32)
+                for c, a in enumerate(a0s):
+                    if a is not None:
+                        a0_mat[:, c] = self._pad_a0(a, vars_p)
+            # Same design => same real obs for every member of the group.
+            obs_real = np.asarray(req0.x).shape[0]
+            atol = self._padded_atol(spec.atol, obs_real * k, obs_p * k_pad)
+            deadlines = [requests[i].deadline_at for i in idxs
+                         if requests[i].deadline_at is not None]
         rebuild = None
         if key is not None:
             rebuild = lambda: self._design_entry(  # noqa: E731
@@ -1017,22 +1078,24 @@ class SolverServeEngine:
         # HBM saving of the flush path — see types.donate_default).
         res, fspec, fentry, fplace, retries, diverged, a0_used = \
             self._attempt_solve(
-                spec, entry, ys, atol, a0_mat, placement,
+                spec, entry, ys, atol, a0_mat, placement, host,
                 deadline_at=min(deadlines) if deadlines else None,
                 rebuild=rebuild, sse0=sse0, need_multi=True)
-        dt = obs.now() - t0
-        path = self._record_solve(fspec, fplace, "multi_rhs", k, dt)
-        coef = np.asarray(res.coef)
-        resid = np.asarray(res.residual)
-        for c, idx in enumerate(idxs):
-            results[idx] = self._strip(
-                requests[idx], coef[:, c], resid[:, c], bucket=bucket,
-                kind="multi_rhs", group_size=k, latency=dt, hit=hit,
-                n_sweeps=res.n_sweeps, converged=res.converged,
-                entry=fentry,
-                warm=a0_used is not None and a0s[c] is not None,
-                placement=fplace, method=fspec.method, path=path,
-                retain_warm=not diverged, retries=retries)
+        dt = obs.now() - t0 - host.fetch_s
+        with host.fetch():
+            path = self._record_solve(fspec, fplace, "multi_rhs", k, dt)
+            coef = np.asarray(res.coef)
+            resid = np.asarray(res.residual)
+            for c, idx in enumerate(idxs):
+                results[idx] = self._strip(
+                    requests[idx], coef[:, c], resid[:, c], bucket=bucket,
+                    kind="multi_rhs", group_size=k, latency=dt, hit=hit,
+                    n_sweeps=res.n_sweeps, converged=res.converged,
+                    entry=fentry,
+                    warm=a0_used is not None and a0s[c] is not None,
+                    placement=fplace, method=fspec.method, path=path,
+                    retain_warm=not diverged, retries=retries)
+        host.stamp([results[idx] for idx in idxs])
         with self._stats_lock:
             self.stats.solver_calls += 1
             self.stats.multi_rhs_groups += 1
@@ -1040,7 +1103,8 @@ class SolverServeEngine:
             if fplace is not None and fplace.sharded:
                 self.stats.sharded_solves += 1
 
-    def _solve_vmapped(self, requests, singles, bucket, results):
+    def _solve_vmapped(self, requests, singles, bucket, results,
+                       flush_time=None):
         """Stack same-bucket single-design requests into one vmapped solve.
 
         Degradation (retry ladder): a raised vmapped batch is not retried
@@ -1051,7 +1115,8 @@ class SolverServeEngine:
         to_path="single"}`` once per member.
         """
         try:
-            self._solve_vmapped_inner(requests, singles, bucket, results)
+            self._solve_vmapped_inner(requests, singles, bucket, results,
+                                      flush_time)
             return
         except Exception as exc:
             if not self.config.retry_ladder:
@@ -1069,102 +1134,109 @@ class SolverServeEngine:
                 continue
             try:
                 self._solve_one(requests, idx, entry, hit, bucket, results,
-                                None, key)
+                                None, key, flush_time)
             except Exception as exc:
                 self._fail(requests, [idx], bucket, exc, results)
 
-    def _solve_vmapped_inner(self, requests, singles, bucket, results):
+    def _solve_vmapped_inner(self, requests, singles, bucket, results,
+                             flush_time=None):
+        host = (flush_time or _HostTime()).unit()
         obs_p, vars_p = bucket
         req0 = requests[singles[0][0]]
-        spec = self.spec_for(req0)
-        mentry = solver_method(spec.method)
         b = len(singles)
         b_pad = next_pow2(b)
         # Pad the batch by replicating the last system (discarded below) so
         # the vmapped program only ever compiles for power-of-two batches.
         padded = singles + [singles[-1]] * (b_pad - b)
-        xs = jnp.stack([entry.x_pad for _, entry, _, _ in padded])
-        ys = jnp.asarray(np.stack(
-            [pad_y(np.asarray(requests[i].y, np.float32), obs_p)
-             for i, _, _, _ in padded]))
-        a0s = [self._resolve_a0(requests[i], e) for i, e, _, _ in padded]
-        warm = any(a is not None for a in a0s)
-        solver = _vmapped_solver(spec.canonical().replace(atol=0.0), warm)
-        # Per-element padding-corrected atol (real obs varies within a
-        # bucket); traced, so it never forces a recompile.
-        atols = jnp.asarray([
-            self._padded_atol(spec.atol, np.asarray(requests[i].x).shape[0],
-                              obs_p)
-            for i, _, _, _ in padded], dtype=jnp.float32)
-        if mentry.blocked:
-            cns = jnp.stack(
-                [e.cn_for_thr(spec.thr) for _, e, _, _ in padded])
-        else:
-            cns = jnp.stack([e.cn for _, e, _, _ in padded])
-        if mentry.needs_chol:
-            chols = jnp.stack(
-                [e.chol_for(spec.thr, spec.ridge) for _, e, _, _ in padded])
-            args = (xs, ys, cns, atols, chols)
-        else:
-            args = (xs, ys, cns, atols)
-        if warm:
-            a0_mat = np.zeros((b_pad, vars_p), np.float32)
-            for row, a in enumerate(a0s):
-                if a is not None:
-                    a0_mat[row] = self._pad_a0(a, vars_p)
-            args = args + (jnp.asarray(a0_mat),)
+        with host.build():
+            spec = self.spec_for(req0)
+            mentry = solver_method(spec.method)
+            xs = jnp.stack([entry.x_pad for _, entry, _, _ in padded])
+            ys = jnp.asarray(np.stack(
+                [pad_y(np.asarray(requests[i].y, np.float32), obs_p)
+                 for i, _, _, _ in padded]))
+            a0s = [self._resolve_a0(requests[i], e) for i, e, _, _ in padded]
+            warm = any(a is not None for a in a0s)
+            solver = _vmapped_solver(spec.canonical().replace(atol=0.0), warm)
+            # Per-element padding-corrected atol (real obs varies within a
+            # bucket); traced, so it never forces a recompile.
+            atols = jnp.asarray([
+                self._padded_atol(spec.atol, np.asarray(requests[i].x).shape[0],
+                                  obs_p)
+                for i, _, _, _ in padded], dtype=jnp.float32)
+            if mentry.blocked:
+                cns = jnp.stack(
+                    [e.cn_for_thr(spec.thr) for _, e, _, _ in padded])
+            else:
+                cns = jnp.stack([e.cn for _, e, _, _ in padded])
+            if mentry.needs_chol:
+                chols = jnp.stack(
+                    [e.chol_for(spec.thr, spec.ridge) for _, e, _, _ in padded])
+                args = (xs, ys, cns, atols, chols)
+            else:
+                args = (xs, ys, cns, atols)
+            if warm:
+                a0_mat = np.zeros((b_pad, vars_p), np.float32)
+                for row, a in enumerate(a0s):
+                    if a is not None:
+                        a0_mat[row] = self._pad_a0(a, vars_p)
+                args = args + (jnp.asarray(a0_mat),)
         t0 = obs.now()
         faults.maybe_raise("solver.raise", f"vmap:{spec.method}")
-        with obs.profile_region(f"solve/vmap/{spec.method}"):
+        with obs.span(f"solve/vmap/{spec.method}"):
             res = solver(*args)
             jax.block_until_ready(res.coef)
         dt = obs.now() - t0
-        forced = faults.hit("solver.diverge", f"vmap:{spec.method}")
-        # The vmapped program is one jit'd stack — the eager dispatch shims
-        # never run inside it, so the path is "vmap" by construction.
-        obs.consume_dispatch()
-        path = self._record_solve(spec, None, "vmap", b, dt, path="vmap")
-        coef = np.asarray(res.coef)
-        resid = np.asarray(res.residual)
-        conv_b = np.asarray(res.converged)
-        hist_b = np.asarray(res.history, np.float32)
+        with host.fetch():
+            forced = faults.hit("solver.diverge", f"vmap:{spec.method}")
+            # The vmapped program is one jit'd stack — the eager dispatch shims
+            # never run inside it, so the path is "vmap" by construction.
+            obs.consume_dispatch()
+            path = self._record_solve(spec, None, "vmap", b, dt, path="vmap")
+            coef = np.asarray(res.coef)
+            resid = np.asarray(res.residual)
+            conv_b = np.asarray(res.converged)
+            hist_b = np.asarray(res.history, np.float32)
 
-        def row_retain(row: int) -> bool:
-            # Per-row warm retention: the batched analogue of
-            # core.types.warm_retention_ok (which is scalar-only).
-            if forced is not None:
-                return False
-            if bool(conv_b[row]):
-                return True
-            h = hist_b[row][np.isfinite(hist_b[row])]
-            return not (h.size >= 2 and float(h[-1]) > 1.01 * float(h[0]))
+            def row_retain(row: int) -> bool:
+                # Per-row warm retention: the batched analogue of
+                # core.types.warm_retention_ok (which is scalar-only).
+                if forced is not None:
+                    return False
+                if bool(conv_b[row]):
+                    return True
+                h = hist_b[row][np.isfinite(hist_b[row])]
+                return not (h.size >= 2 and float(h[-1]) > 1.01 * float(h[0]))
 
-        for row, (idx, entry, hit, _) in enumerate(singles):
-            results[idx] = self._strip(
-                requests[idx], coef[row], resid[row], bucket=bucket,
-                kind="vmap", group_size=b, latency=dt, hit=hit,
-                n_sweeps=res.n_sweeps[row], converged=res.converged[row],
-                entry=entry, warm=a0s[row] is not None,
-                method=spec.method, path=path,
-                retain_warm=row_retain(row))
+            for row, (idx, entry, hit, _) in enumerate(singles):
+                results[idx] = self._strip(
+                    requests[idx], coef[row], resid[row], bucket=bucket,
+                    kind="vmap", group_size=b, latency=dt, hit=hit,
+                    n_sweeps=res.n_sweeps[row], converged=res.converged[row],
+                    entry=entry, warm=a0s[row] is not None,
+                    method=spec.method, path=path,
+                    retain_warm=row_retain(row))
+        host.stamp([results[idx] for idx, _, _, _ in singles])
         with self._stats_lock:
             self.stats.solver_calls += 1
             self.stats.vmap_batches += 1
             self.stats.vmap_requests += b
 
     def _solve_one(self, requests, idx, entry, hit, bucket, results,
-                   placement=None, key=None):
+                   placement=None, key=None, flush_time=None):
+        host = (flush_time or _HostTime()).unit()
         req = requests[idx]
-        spec = self.spec_for(req)
-        y_real = np.asarray(req.y, np.float32)
-        y_pad = pad_y(y_real, bucket[0])
-        atol = self._padded_atol(spec.atol, y_real.shape[0], bucket[0])
-        a0 = None
-        if solver_method(spec.method).iterative:
-            a0 = self._resolve_a0(req, entry)
-        a0_pad = None
-        if a0 is not None:
-            a0_pad = self._pad_a0(a0, bucket[1])
+        with host.build():
+            spec = self.spec_for(req)
+            y_real = np.asarray(req.y, np.float32)
+            y_pad = pad_y(y_real, bucket[0])
+            atol = self._padded_atol(spec.atol, y_real.shape[0], bucket[0])
+            a0 = None
+            if solver_method(spec.method).iterative:
+                a0 = self._resolve_a0(req, entry)
+            a0_pad = None
+            if a0 is not None:
+                a0_pad = self._pad_a0(a0, bucket[1])
         rebuild = None
         if key is not None:
             rebuild = lambda: self._design_entry(  # noqa: E731
@@ -1173,18 +1245,20 @@ class SolverServeEngine:
         # Host buffers in — see _solve_multi_rhs on donation.
         res, fspec, fentry, fplace, retries, diverged, a0_used = \
             self._attempt_solve(spec, entry, y_pad, atol, a0_pad, placement,
-                                deadline_at=req.deadline_at,
+                                host, deadline_at=req.deadline_at,
                                 rebuild=rebuild,
                                 sse0=float(np.dot(y_real, y_real)))
-        dt = obs.now() - t0
-        path = self._record_solve(fspec, fplace, "single", 1, dt)
-        results[idx] = self._strip(
-            req, res.coef, res.residual, bucket=bucket, kind="single",
-            group_size=1, latency=dt, hit=hit, n_sweeps=res.n_sweeps,
-            converged=res.converged, entry=fentry,
-            warm=a0_used is not None, placement=fplace,
-            method=fspec.method, path=path, retain_warm=not diverged,
-            retries=retries)
+        dt = obs.now() - t0 - host.fetch_s
+        with host.fetch():
+            path = self._record_solve(fspec, fplace, "single", 1, dt)
+            results[idx] = self._strip(
+                req, res.coef, res.residual, bucket=bucket, kind="single",
+                group_size=1, latency=dt, hit=hit, n_sweeps=res.n_sweeps,
+                converged=res.converged, entry=fentry,
+                warm=a0_used is not None, placement=fplace,
+                method=fspec.method, path=path, retain_warm=not diverged,
+                retries=retries)
+        host.stamp([results[idx]])
         with self._stats_lock:
             self.stats.solver_calls += 1
             self.stats.single_solves += 1
