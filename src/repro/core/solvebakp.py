@@ -105,7 +105,6 @@ def _solvebakp_impl(
             f"a0 must be ({nvars},) or ({nvars}, {nrhs}) matching x columns "
             f"and y RHS count, got {a0.shape}")
     x_pad, mask, nblocks = _pad_cols(x, thr)
-    xb = x_pad.reshape(obs, nblocks, thr)
 
     if cn is None:
         cn = column_norms_sq(x_pad)
@@ -114,7 +113,8 @@ def _solvebakp_impl(
 
     if mode == "gram":
         if chol is None:
-            chol = block_gram_cholesky(xb, ridge)
+            chol = block_gram_cholesky(x_pad.reshape(obs, nblocks, thr),
+                                       ridge)
     elif mode == "jacobi":
         chol = None
     else:
@@ -132,7 +132,9 @@ def _solvebakp_impl(
 
     def block_step(carry, b):
         ab, e = carry
-        xblk = lax.dynamic_index_in_dim(xb, b, axis=1, keepdims=False)
+        # Column block b read where it lies in x: whole (8, 128) tiles when
+        # thr is a multiple of 128, so no blocked copy of x is made.
+        xblk = lax.dynamic_slice_in_dim(x_pad, b * thr, thr, axis=1)
         xblk = xblk.astype(jnp.float32)  # (obs, thr)
         g = xblk.T @ e  # (thr, k)  ⟨x_k, e⟩ for all k in block, all RHS
         if mode == "jacobi":

@@ -1,6 +1,6 @@
 """Compile rehearsal: the served path's Pallas kernels, at the sizes
-chip_smoke.py runs them, compiled by the TPU compiler for a described (not
-attached) v5e chip.
+chip_smoke.py runs them, and the XLA gram solve at the tall deployment's
+size, compiled by the TPU compiler for a described (not attached) v5e chip.
 
 Interpret mode accepts what Mosaic refuses — unaligned slices, slices of
 loaded values, more VMEM than the scoped limit — so these compiles are
@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import solvebakp
 from repro.kernels import (block_update, fused_fits, fused_solve,
                            score_features, solvebakp_persweep_kernel,
                            stream_fits, stream_solve)
@@ -108,6 +109,28 @@ def test_persweep_compiles(one_chip, variant, k):
 
     _compile(run, one_chip, ((nvars, obs), F32),
              ((obs, k) if k > 1 else (obs,), F32), ((nvars,), F32))
+
+
+@pytest.mark.parametrize("k", [1, 4])
+def test_xla_gram_solve_reads_blocks_in_place(one_chip, k):
+    """The XLA ``bakp_gram`` program at the tall deployment's padded shape
+    (1048576 x 1024 fp32, thr 128, factors precomputed as the serving
+    handle passes them).  Each column block is sliced out of x where it
+    lies: the program's scratch stays below one (obs, thr) block, so it
+    holds neither a blocked copy of x nor a materialised block."""
+    obs, nvars, thr = 1 << 20, 1024, 128
+
+    def run(x, y, cn, chol):
+        return solvebakp(x, y, thr=thr, max_iter=50, rtol=1e-8, mode="gram",
+                         cn=cn, chol=chol, donate=False)
+
+    args = [jax.ShapeDtypeStruct(s, F32, sharding=one_chip) for s in
+            ((obs, nvars), (obs, k) if k > 1 else (obs,), (nvars,),
+             (nvars // thr, thr, thr))]
+    compiled = jax.jit(run).lower(*args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()  # the XLA program
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < obs * thr * 4, temp
 
 
 def test_block_kernels_compile(one_chip):
