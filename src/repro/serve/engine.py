@@ -54,10 +54,12 @@ padding stripped and per-request SSE recomputed from the stripped residual.
 Spans (``repro.obs.span``; on the device trace while profiling):
 ``engine.flush`` around a whole flush; ``engine.build`` around the host
 work before a solver call (the flush's grouping and design lookups, then
-each unit's padding of y and a0); ``solve/<method>`` (``solve/vmap/<method>``)
-around the solver launch; ``engine.fetch`` from the result being ready on
-the device to the finished ``ServedSolve``.  Each request's telemetry
-carries its unit's ``build_s``, ``solve_s`` and ``fetch_s``.
+each unit's padding of y and a0); ``solve/<method>`` around the solver
+launch (``solve/vmap/<method>`` for a vmapped batch, and
+``solve/<placement kind>/<method>`` on a mesh placement, such as
+``solve/obs_sharded/bakp_gram``); ``engine.fetch`` from the result being
+ready on the device to the finished ``ServedSolve``.  Each request's
+telemetry carries its unit's ``build_s``, ``solve_s`` and ``fetch_s``.
 
 Flushing is exception-safe: a batch whose solver raises is isolated — every
 request in it gets an error result (``ServedSolve.error`` set, zero
@@ -763,7 +765,9 @@ class SolverServeEngine:
         eff = spec.replace(atol=atol)
         if placement is not None and placement.kind == "mesh_2d":
             eff = eff.replace(omega=self.config.omega_2d)
-        with obs.span(f"solve/{eff.method}"):
+        where = (f"{placement.kind}/" if placement is not None
+                 and placement.sharded else "")
+        with obs.span(f"solve/{where}{eff.method}"):
             return entry.solve(y_dev, a0, spec=eff, placement=placement,
                                mesh=self.mesh)
 

@@ -5,6 +5,7 @@ CPU devices (the main test process keeps the single-device view, see
 tests/conftest.py); placement policy and cache-locking tests run in-process
 — they don't touch device state.
 """
+import json
 import os
 import subprocess
 import sys
@@ -105,6 +106,136 @@ def test_sharded_engine_parity_subprocess():
                        capture_output=True, text=True, env=env, timeout=600)
     assert p.returncode == 0, p.stdout + "\n" + p.stderr
     assert "PARITY_OK" in p.stdout
+
+
+# The served path of the obs-sharded deployment (``bench/configs/
+# sharded_4m.json``) at a tiny size: eight single-RHS ``bakp_gram``
+# requests, outstanding at once as eight closed-loop callers leave them,
+# through ``AsyncDispatcher`` on a four-device mesh engine with the
+# configuration's placement policy (its cell threshold lowered to this
+# shape).  8,192 x 300 pads to the bucket 8,192 x 512: four column blocks
+# of thr 128, each a psum of a (128, 8) partial over the four row shards.
+# Prints one JSON line for the tests below to judge.
+CLOSED8_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import json
+    import threading
+    import numpy as np
+    from repro import obs
+    from repro.core import SolverSpec
+    from repro.serve import (AsyncDispatcher, DispatchConfig, PlacementPolicy,
+                             ServeConfig, SolveRequest, SolverServeEngine,
+                             build_serve_mesh)
+
+    OBS, VARS, K = 8192, 300, 8
+    rng = np.random.default_rng(15)
+    x = rng.standard_normal((OBS, VARS), dtype=np.float32)
+    a = rng.uniform(1.0, 2.0, (K, VARS)) * rng.choice([-1.0, 1.0], (K, VARS))
+    clean = (a.astype(np.float32) @ x.T)
+    noise = rng.standard_normal((K, OBS)).astype(np.float32)
+    ys = clean + (1e-3 * np.linalg.norm(clean, axis=1, keepdims=True)
+                  / np.linalg.norm(noise, axis=1, keepdims=True)) * noise
+    spec = SolverSpec(method="bakp_gram", rtol=1e-8, max_iter=50)
+
+    obs.set_enabled(True)
+    obs.get_tracer().clear()
+    policy = PlacementPolicy(obs_shard_min_cells=OBS * 512, rhs_shard_min_k=32)
+    mesh_eng = SolverServeEngine(ServeConfig(placement_policy=policy),
+                                 mesh=build_serve_mesh("4"))
+    # max_batch = K fires the group once the eighth request joins it.
+    cfg = DispatchConfig(max_batch=K, idle_timeout_s=5.0)
+    results = [None] * K
+    start = threading.Barrier(K)
+    with AsyncDispatcher(mesh_eng, cfg) as disp:
+        def client(i):
+            start.wait()
+            ticket = disp.submit(SolveRequest(
+                x=x, y=ys[i], spec=spec, design_key="sharded",
+                request_id=f"c{i}"))
+            results[i] = ticket.result(timeout=300)
+        threads = [threading.Thread(target=client, args=(i,))
+                   for i in range(K)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+    mesh_spans = [s.name for s in obs.get_tracer().spans()
+                  if s.name.startswith("solve/")]
+
+    obs.get_tracer().clear()
+    single = SolverServeEngine(ServeConfig()).serve([SolveRequest(
+        x=x, y=ys[0], spec=spec, design_key="single", request_id="s0")])
+    single_spans = [s.name for s in obs.get_tracer().spans()
+                    if s.name.startswith("solve/")]
+
+    x64 = x.astype(np.float64)
+    y64 = ys.astype(np.float64)
+    ref = np.linalg.lstsq(x64, y64.T, rcond=None)[0].T
+    coef = np.stack([np.asarray(r.coef, np.float64) for r in results])
+    resid = np.stack([np.asarray(r.residual, np.float64) for r in results])
+    coef_err = (np.linalg.norm(coef - ref, axis=1)
+                / np.linalg.norm(ref, axis=1))
+    resid_err = (np.linalg.norm(resid - (y64 - coef @ x64.T), axis=1)
+                 / np.linalg.norm(y64, axis=1))
+    print(json.dumps({
+        "ok": [r.ok for r in results],
+        "group_size": [r.group_size for r in results],
+        "batch_kind": [r.batch_kind for r in results],
+        "placement": [r.placement for r in results],
+        "kernel_path": [r.telemetry.kernel_path for r in results],
+        "retries": [r.retries for r in results],
+        "coef_rel_err": coef_err.tolist(),
+        "resid_rel_err": resid_err.tolist(),
+        "mesh_spans": mesh_spans,
+        "single_spans": single_spans,
+        "single_placement": single[0].placement,
+    }))
+""")
+
+
+@pytest.fixture(scope="module")
+def closed8_run():
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.abspath(
+        os.path.join(os.path.dirname(__file__), "..", "src"))
+    p = subprocess.run([sys.executable, "-c", CLOSED8_SCRIPT],
+                       capture_output=True, text=True, env=env, timeout=600)
+    assert p.returncode == 0, p.stdout + "\n" + p.stderr[-3000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
+def test_closed8_group_is_one_obs_sharded_solve(closed8_run):
+    r = closed8_run
+    assert all(r["ok"]), r
+    assert r["group_size"] == [8] * 8
+    assert r["batch_kind"] == ["multi_rhs"] * 8
+    assert r["placement"] == ["obs_sharded"] * 8
+    assert r["kernel_path"] == ["sharded"] * 8
+    assert r["retries"] == [0] * 8
+
+
+def test_closed8_answers_match_float64_lstsq(closed8_run):
+    """Against ``numpy.linalg.lstsq`` in float64 of the same data.
+
+    Coefficients within 1e-6 relative: the solve stops at rtol 1e-8 on the
+    SSE, which on this well-conditioned Gaussian design leaves a
+    coefficient error of order 1e-7, and float32 sums over 8,192 rows add
+    rounding of the same order.  The targets carry noise of 1e-3 of
+    ``|x a|``, so a solve that left a shard's rows out of the psum would
+    be off by about 1e-4, a hundred times the tolerance.  Residuals within
+    1e-6 of ``|y|``: the served residual is the solve's own float32 running
+    residual, against float64 ``y - x a`` of the served coefficients."""
+    assert max(closed8_run["coef_rel_err"]) < 1e-6, closed8_run
+    assert max(closed8_run["resid_rel_err"]) < 1e-6, closed8_run
+
+
+def test_solve_span_is_named_by_placement(closed8_run):
+    """A mesh placement's solve span carries its kind; a single-device
+    solve keeps ``solve/<method>``."""
+    assert closed8_run["mesh_spans"] == ["solve/obs_sharded/bakp_gram"]
+    assert closed8_run["single_placement"] == "single"
+    assert closed8_run["single_spans"] == ["solve/bakp_gram"]
 
 
 # ----------------------------------------------------------- policy (pure)
