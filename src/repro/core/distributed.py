@@ -82,24 +82,32 @@ def _bakp_local(x_loc, y_loc, a0_loc, atol_sse, rtol, *, nvars_loc: int,
     real (unpadded) group size, and must not retrace the shard_map program
     — mirroring the single-device solvers, where they are jit operands.
     """
-    obs_loc = x_loc.shape[0]
     nrhs_loc = y_loc.shape[1]
     nblocks = -(-nvars_loc // thr)
     pad = nblocks * thr - nvars_loc
     if pad:
         x_loc = jnp.pad(x_loc, ((0, 0), (0, pad)))
-    xb = x_loc.reshape(obs_loc, nblocks, thr)
     mask = (jnp.arange(nblocks * thr) < nvars_loc).astype(jnp.float32)
     mask_b = mask.reshape(nblocks, thr)
 
-    xf = xb.astype(jnp.float32)
+    def block(b):
+        # Column block b read where it lies in the shard: whole (8, 128)
+        # tiles when thr is a multiple of 128, so no blocked copy is made.
+        xblk = lax.dynamic_slice_in_dim(x_loc, b * thr, thr, axis=1)
+        return xblk.astype(jnp.float32)  # (obs_loc, thr)
+
     if mode == "gram":
-        gram = _psum(jnp.einsum("obt,obs->bts", xf, xf), g_axes)
+        def block_gram(b):
+            xblk = block(b)
+            return xblk.T @ xblk
+
+        gram = _psum(lax.map(block_gram, jnp.arange(nblocks)), g_axes)
         gram = gram + ridge * jnp.eye(thr, dtype=jnp.float32)[None]
         factor = jax.vmap(
             lambda g: jax.scipy.linalg.cholesky(g, lower=True))(gram)
     else:
-        cn = _psum(jnp.einsum("obt,obt->bt", xf, xf), g_axes)
+        xf = x_loc.astype(jnp.float32)
+        cn = _psum(jnp.sum(xf * xf, axis=0).reshape(nblocks, thr), g_axes)
         factor = safe_inv(cn) * mask_b
 
     if a0_loc is None:
@@ -118,8 +126,7 @@ def _bakp_local(x_loc, y_loc, a0_loc, atol_sse, rtol, *, nvars_loc: int,
 
     def block_step(carry, b):
         ab, e = carry
-        xblk = lax.dynamic_index_in_dim(xb, b, axis=1, keepdims=False)
-        xblk = xblk.astype(jnp.float32)
+        xblk = block(b)
         g = _psum(xblk.T @ e, g_axes)  # (thr, k) fused collective per block
         if mode == "jacobi":
             da = g * lax.dynamic_index_in_dim(

@@ -16,9 +16,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import make_system
-from repro.core import (solvebakp, solvebakp_rhs_sharded,
-                        solvebakp_vars_sharded)
+from conftest import blocked_system, make_system
+from repro.core import (solvebakp, solvebakp_obs_sharded,
+                        solvebakp_rhs_sharded, solvebakp_vars_sharded)
 from repro.launch.mesh import make_debug_mesh
 
 SCRIPT = textwrap.dedent("""
@@ -244,6 +244,46 @@ class TestRhsShardedApi:
                                   rtol=rtol)
         after = _sharded_program.cache_info().currsize
         assert after - before <= 1  # one program serves every tolerance
+
+
+_MESH_SOLVERS = {"obs": solvebakp_obs_sharded, "rhs": solvebakp_rhs_sharded}
+
+
+@pytest.mark.parametrize("warm", [False, True])
+@pytest.mark.parametrize("kind,k", [("obs", 1), ("obs", 4), ("rhs", 4)])
+@pytest.mark.parametrize("thr", [48, 128])
+@pytest.mark.parametrize("mode", ["gram", "jacobi"])
+def test_sharded_blocked_solve_matches_lstsq(mesh1, mode, thr, kind, k,
+                                             warm):
+    """Each column block is sliced out of the local shard (the last one
+    through the zero padding), for the block Gram, the column norms and
+    every block step: the mesh solve reaches the float64 solution for
+    every block width, RHS count, start and sharding."""
+    solve = _MESH_SOLVERS[kind]
+    x, y, ref, a0 = blocked_system(16, k)
+    res = solve(jnp.array(x), jnp.array(y), mesh1, thr=thr, max_iter=60,
+                mode=mode, a0=jnp.array(a0) if warm else None)
+    assert res.coef.shape == ref.shape
+    np.testing.assert_allclose(np.array(res.coef), ref, rtol=0, atol=5e-6)
+    np.testing.assert_allclose(np.array(res.residual),
+                               y - x @ np.array(res.coef), rtol=0,
+                               atol=2e-4)
+    if warm:  # the start is honoured: the first sweep begins near ref
+        cold = solve(jnp.array(x), jnp.array(y), mesh1, thr=thr, max_iter=1,
+                     mode=mode)
+        assert float(res.history[0]) < float(cold.history[0])
+
+
+def test_obs_sharded_matches_single_device_sweep_for_sweep(mesh1):
+    """The mesh program's block Gram, formed block by block from in-place
+    slices, and the single-device program's give the same sweeps."""
+    x, y, _, _ = blocked_system(17, 1)
+    kw = dict(thr=128, max_iter=5, mode="gram")
+    r1 = solvebakp(jnp.array(x), jnp.array(y), **kw)
+    r2 = solvebakp_obs_sharded(jnp.array(x), jnp.array(y), mesh1, **kw)
+    assert int(r1.n_sweeps) == int(r2.n_sweeps) == 5
+    np.testing.assert_allclose(np.array(r2.history), np.array(r1.history),
+                               rtol=1e-4)
 
 
 def test_mesh_builder_no_axistype_needed():

@@ -12,7 +12,7 @@ try:
 except ImportError:
     HAS_HYPOTHESIS = False
 
-from conftest import make_system
+from conftest import blocked_system, make_system
 from repro.core import SolverSpec, solvebakp
 from repro.core.methods import _bakp_vmap_one
 from repro.core.solvebakp import block_gram_cholesky
@@ -91,21 +91,6 @@ class TestSolveBakP:
             pass
 
 
-def _blocked_system(seed, k, obs=2048, nvars=150):
-    """Noisy system whose vars (150) is a multiple of neither 48 nor 128,
-    with its float64 least-squares solution."""
-    r = np.random.default_rng(seed)
-    x = r.normal(size=(obs, nvars)).astype(np.float32)
-    y = (x @ r.normal(size=(nvars, k)).astype(np.float32)
-         + 1e-3 * r.normal(size=(obs, k)).astype(np.float32))
-    ref = np.linalg.lstsq(x.astype(np.float64), y.astype(np.float64),
-                          rcond=None)[0]
-    a0 = (ref + 0.05 * r.normal(size=ref.shape)).astype(np.float32)
-    if k == 1:
-        y, ref, a0 = y[:, 0], ref[:, 0], a0[:, 0]
-    return x, y, ref, a0
-
-
 @pytest.mark.parametrize("warm", [False, True])
 @pytest.mark.parametrize("k", [1, 4])
 @pytest.mark.parametrize("thr", [48, 128])
@@ -114,7 +99,7 @@ def test_blocked_solve_matches_lstsq(mode, thr, k, warm):
     """Each column block is read straight out of x (the last one through
     the zero padding): the blocked solve reaches the float64 solution for
     every block width, RHS count and start."""
-    x, y, ref, a0 = _blocked_system(14, k)
+    x, y, ref, a0 = blocked_system(14, k)
     res = solvebakp(jnp.array(x), jnp.array(y), thr=thr, max_iter=60,
                     mode=mode, a0=jnp.array(a0) if warm else None)
     assert res.coef.shape == ref.shape
@@ -134,7 +119,7 @@ def test_vmapped_batch_matches_lstsq(mode, warm):
     """The engine's same-shape batch path (``vmap`` over designs, so the
     block slice's axis moves under it) solves two designs of one shape."""
     thr, k = 48, 4
-    systems = [_blocked_system(seed, k) for seed in (21, 22)]
+    systems = [blocked_system(seed, k) for seed in (21, 22)]
     xs = jnp.array(np.stack([s[0] for s in systems]))
     ys = jnp.array(np.stack([s[1] for s in systems]))
     a0s = jnp.array(np.stack([s[3] for s in systems]))

@@ -1,6 +1,7 @@
 """Compile rehearsal: the served path's Pallas kernels, at the sizes
-chip_smoke.py runs them, and the XLA gram solve at the tall deployment's
-size, compiled by the TPU compiler for a described (not attached) v5e chip.
+chip_smoke.py runs them, and the XLA gram solve at the tall and sharded
+deployments' sizes, compiled by the TPU compiler for described (not
+attached) v5e chips.
 
 Interpret mode accepts what Mosaic refuses — unaligned slices, slices of
 loaded values, more VMEM than the scoped limit — so these compiles are
@@ -15,10 +16,12 @@ import importlib
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import solvebakp
+from repro.core import solvebakp, solvebakp_obs_sharded
 from repro.kernels import (block_update, fused_fits, fused_solve,
                            score_features, solvebakp_persweep_kernel,
                            stream_fits, stream_solve)
@@ -131,6 +134,32 @@ def test_xla_gram_solve_reads_blocks_in_place(one_chip, k):
     assert "tpu_custom_call" not in compiled.as_text()  # the XLA program
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < obs * thr * 4, temp
+
+
+def test_sharded_gram_solve_reads_blocks_in_place(topo):
+    """The obs-sharded ``bakp_gram`` program at the sharded deployment's
+    padded shape (4194304 x 1024 fp32 over the four chips of a v5e:2x2,
+    thr 128, k = 8, cold).  Each chip slices its column blocks out of its
+    local shard where they lie, for the block Gram and for every block
+    step: the per-chip scratch stays below one (obs_loc, thr) block.  The
+    program that cut each block from a per-call blocked copy of the shard
+    needed 4,832,871,424 B of scratch here."""
+    obs, nvars, thr, k = 1 << 22, 1024, 128, 8
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+
+    def run(x, y):
+        return solvebakp_obs_sharded(x, y, mesh, thr=thr, max_iter=50,
+                                     rtol=1e-8, mode="gram")
+
+    rows = NamedSharding(mesh, P("data", None))
+    args = [jax.ShapeDtypeStruct((obs, nvars), F32, sharding=rows),
+            jax.ShapeDtypeStruct((obs, k), F32, sharding=rows)]
+    compiled = jax.jit(run).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text  # the XLA program
+    assert "all-reduce" in text           # the psums across the chips
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (obs // 4) * thr * 4, temp
 
 
 def test_block_kernels_compile(one_chip):
