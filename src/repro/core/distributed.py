@@ -1,6 +1,6 @@
 """Distributed SolveBakP — the paper's §6 parallelisation mapped onto a TPU mesh.
 
-Four shardings (DESIGN.md §3/§6):
+Four shardings, each running ``solvebakp.block_cd`` on its local shard:
 
 * **obs-sharded** (`solvebakp_obs_sharded`) — rows of ``x`` shard over the
   data-parallel mesh axes.  This is the paper's "only one column needs to be
@@ -47,120 +47,10 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.types import (SolveResult, fp32_matmuls, safe_inv,
-                              sweep_stop_flags)
-
-
-def _psum(v, axes):
-    return lax.psum(v, axes) if axes else v
-
-
-@fp32_matmuls
-def _bakp_local(x_loc, y_loc, a0_loc, atol_sse, rtol, *, nvars_loc: int,
-                thr: int, max_iter: int, omega: float, mode: str,
-                ridge: float, g_axes: Tuple[str, ...],
-                corr_axes: Tuple[str, ...], sse_axes: Tuple[str, ...]):
-    """Per-device SolveBakP sweeps over a local (rows × cols) shard.
-
-    The same body serves every sharding; only the collective axes differ:
-      * ``g_axes``    — block inner products ⟨x_k, e⟩ (and the block Gram /
-                        column-norm factors) partial-sum over these axes;
-      * ``corr_axes`` — the rank-thr residual correction psums over these
-                        (Jacobi across column shards);
-      * ``sse_axes``  — the per-sweep SSE psums over these, so the stopping
-                        decision (and history) is global and every device
-                        runs the same trip count.
-
-    ``x_loc`` is (obs_loc, nvars_loc); ``y_loc``/``a0_loc`` carry the local
-    slice of right-hand sides, (obs_loc, k_loc) / (nvars_loc·padded, k_loc).
-    ``a0_loc`` may be None (cold start — skips the residual matmul).
-    ``atol_sse``/``rtol`` are *traced* replicated scalars, not compile-time
-    constants: the serving engine's padding-corrected atol varies with the
-    real (unpadded) group size, and must not retrace the shard_map program
-    — mirroring the single-device solvers, where they are jit operands.
-    """
-    nrhs_loc = y_loc.shape[1]
-    nblocks = -(-nvars_loc // thr)
-    pad = nblocks * thr - nvars_loc
-    if pad:
-        x_loc = jnp.pad(x_loc, ((0, 0), (0, pad)))
-    mask = (jnp.arange(nblocks * thr) < nvars_loc).astype(jnp.float32)
-    mask_b = mask.reshape(nblocks, thr)
-
-    def block(b):
-        # Column block b read where it lies in the shard: whole (8, 128)
-        # tiles when thr is a multiple of 128, so no blocked copy is made.
-        xblk = lax.dynamic_slice_in_dim(x_loc, b * thr, thr, axis=1)
-        return xblk.astype(jnp.float32)  # (obs_loc, thr)
-
-    if mode == "gram":
-        def block_gram(b):
-            xblk = block(b)
-            return xblk.T @ xblk
-
-        gram = _psum(lax.map(block_gram, jnp.arange(nblocks)), g_axes)
-        gram = gram + ridge * jnp.eye(thr, dtype=jnp.float32)[None]
-        factor = jax.vmap(
-            lambda g: jax.scipy.linalg.cholesky(g, lower=True))(gram)
-    else:
-        xf = x_loc.astype(jnp.float32)
-        cn = _psum(jnp.sum(xf * xf, axis=0).reshape(nblocks, thr), g_axes)
-        factor = safe_inv(cn) * mask_b
-
-    if a0_loc is None:
-        ab0 = jnp.zeros((nblocks, thr, nrhs_loc), jnp.float32)
-        e0 = y_loc.astype(jnp.float32)
-    else:
-        a0p = a0_loc.astype(jnp.float32)
-        if pad:
-            a0p = jnp.pad(a0p, ((0, pad), (0, 0)))
-        ab0 = a0p.reshape(nblocks, thr, nrhs_loc)
-        # Warm residual: column shards each contribute their slice of x@a0.
-        e0 = y_loc.astype(jnp.float32) - _psum(
-            x_loc.astype(jnp.float32) @ a0p, corr_axes)
-    sse0 = _psum(jnp.vdot(e0, e0), sse_axes)
-    history0 = jnp.full((max_iter,), jnp.nan, jnp.float32)
-
-    def block_step(carry, b):
-        ab, e = carry
-        xblk = block(b)
-        g = _psum(xblk.T @ e, g_axes)  # (thr, k) fused collective per block
-        if mode == "jacobi":
-            da = g * lax.dynamic_index_in_dim(
-                factor, b, 0, keepdims=False)[:, None]
-        else:
-            lb = lax.dynamic_index_in_dim(factor, b, 0, keepdims=False)
-            mb = lax.dynamic_index_in_dim(mask_b, b, 0, keepdims=False)
-            da = jax.scipy.linalg.cho_solve((lb, True), g) * mb[:, None]
-        da = omega * da
-        # Residual correction must include every column shard's update:
-        # Jacobi across corr_axes (paper's thread loop, lifted to devices).
-        e = e - _psum(xblk @ da, corr_axes)
-        ab = lax.dynamic_update_index_in_dim(ab, ab[b] + da, b, axis=0)
-        return (ab, e), None
-
-    def sweep_body(state):
-        ab, e, i, sse_prev, history, converged, stop = state
-        (ab, e), _ = lax.scan(block_step, (ab, e), jnp.arange(nblocks))
-        sse = _psum(jnp.vdot(e, e), sse_axes)
-        history = history.at[i].set(sse)
-        converged, stop = sweep_stop_flags(sse, sse_prev, sse0, atol_sse,
-                                           rtol)
-        return ab, e, i + 1, sse, history, converged, stop
-
-    def cond(state):
-        _, _, i, _, _, _, stop = state
-        return (i < max_iter) & ~stop
-
-    ab, e, n, sse, history, converged, _ = lax.while_loop(
-        cond, sweep_body,
-        (ab0, e0, jnp.int32(0), sse0, history0, jnp.bool_(False),
-         jnp.bool_(False)))
-    coef_loc = ab.reshape(nblocks * thr, nrhs_loc)[:nvars_loc]
-    return coef_loc, e, sse, n, converged, history
+from repro.core.solvebakp import block_cd, check_rhs, first_rhs
+from repro.core.types import SolveResult
 
 
 # Per-kind shard_map spec table: (x, y, a0) in-specs and
@@ -195,23 +85,21 @@ def _sharded_program(kind: str, mesh: Mesh, xshape: Tuple[int, int],
     extra residual matmul, mirroring the engine's jit signature split for
     single-device solves).
     """
-    obs, nvars = xshape
     in_x, in_y, in_a0, out_coef, out_e, g_axes, corr_axes, sse_axes = \
         _KINDS[kind](data_axes, model_axis)
-    nvars_loc = nvars // mesh.shape[model_axis] if kind in ("vars", "2d") \
-        else nvars
-    kw = dict(nvars_loc=nvars_loc, thr=thr, max_iter=max_iter, omega=omega,
-              mode=mode, ridge=ridge, g_axes=g_axes, corr_axes=corr_axes,
-              sse_axes=sse_axes)
-    out_specs = (out_coef, out_e, P(), P(), P(), P(None))
+    kw = dict(thr=thr, max_iter=max_iter, mode=mode, g_axes=g_axes,
+              corr_axes=corr_axes, sse_axes=sse_axes)
+    out_specs = SolveResult(out_coef, out_e, P(), P(), P(), P(None))
 
     if warm:
         def run(x_loc, y_loc, a0_loc, atol_sse, rtol):
-            return _bakp_local(x_loc, y_loc, a0_loc, atol_sse, rtol, **kw)
+            return block_cd(x_loc, y_loc, a0_loc, None, None, atol_sse, rtol,
+                            omega, ridge, **kw)
         in_specs = (in_x, in_y, in_a0, P(), P())
     else:
         def run(x_loc, y_loc, atol_sse, rtol):
-            return _bakp_local(x_loc, y_loc, None, atol_sse, rtol, **kw)
+            return block_cd(x_loc, y_loc, None, None, None, atol_sse, rtol,
+                            omega, ridge, **kw)
         in_specs = (in_x, in_y, P(), P())
     return jax.jit(jax.shard_map(run, mesh=mesh, in_specs=in_specs,
                                  out_specs=out_specs, check_vma=False))
@@ -221,17 +109,11 @@ def _solve_sharded(kind, x, y, mesh, *, data_axes, model_axis, thr, max_iter,
                    atol, rtol, omega, mode, ridge, a0):
     """Shared driver: normalise y/a0, run the cached program, reshape back."""
     obs, nvars = x.shape
-    if y.ndim not in (1, 2):
-        raise ValueError(f"y must be (obs,) or (obs, k), got {y.shape}")
+    a0 = None if a0 is None else jnp.asarray(a0)
+    nrhs = check_rhs(y, a0, nvars)
     multi = y.ndim == 2
-    nrhs = y.shape[1] if multi else 1
     y2 = jnp.asarray(y).reshape(obs, nrhs)
     if a0 is not None:
-        a0 = jnp.asarray(a0)
-        if a0.shape not in ((nvars,), (nvars, nrhs)):
-            raise ValueError(
-                f"a0 must be ({nvars},) or ({nvars}, {nrhs}) matching x "
-                f"columns and y RHS count, got {a0.shape}")
         # (vars,) broadcasts across all right-hand sides; materialised so
         # rhs-sharding can slice it per device like any other (vars, k).
         a0 = jnp.broadcast_to(a0.reshape(nvars, -1), (nvars, nrhs))
@@ -260,10 +142,8 @@ def _solve_sharded(kind, x, y, mesh, *, data_axes, model_axis, thr, max_iter,
     atol_sse = jnp.float32(float(obs) * float(nrhs) * float(atol) ** 2)
     rtol_t = jnp.float32(rtol)
     args = ((x, y2) if a0 is None else (x, y2, a0)) + (atol_sse, rtol_t)
-    coef, e, sse, n, converged, history = program(*args)
-    if not multi:
-        coef, e = coef[:, 0], e[:, 0]
-    return SolveResult(coef, e, sse, n, converged, history)
+    res = program(*args)
+    return res if multi else first_rhs(res)
 
 
 def solvebakp_obs_sharded(

@@ -5,7 +5,7 @@ Every method the public API dispatches on is declared here as a
 
   * "bak"        — Algorithm 1, serial cyclic CD (paper-faithful baseline).
   * "bakp"       — Algorithm 2, block-Jacobi CD (paper-faithful parallel).
-  * "bakp_gram"  — beyond-paper exact block CD (DESIGN.md §3).
+  * "bakp_gram"  — beyond-paper exact block CD (``solvebakp`` mode "gram").
   * "bakp_fused" — Algorithm 2 on the fused whole-solve Pallas megakernel
                    (``repro.kernels.fused_solve``): one kernel launch runs
                    every sweep with x/residual/coefficients VMEM-resident

@@ -278,14 +278,8 @@ class PreparedDesign:
         key = (int(thr), float(ridge))
         with self._lock:
             if key not in self.chol:
-                obs_p, vars_p = self._require_x("chol_for").shape
-                nblocks = -(-vars_p // thr)
-                pad = nblocks * thr - vars_p
-                x = self.x_pad
-                if pad:
-                    x = jnp.pad(x, ((0, 0), (0, pad)))
-                xb = x.reshape(obs_p, nblocks, thr)
-                self.chol[key] = block_gram_cholesky(xb, ridge)
+                self.chol[key] = block_gram_cholesky(
+                    self._require_x("chol_for"), int(thr), ridge)
             return self.chol[key]
 
     def x_for_placement(self, placement, smesh) -> jax.Array:

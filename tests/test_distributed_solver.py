@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 
 from conftest import blocked_system, make_system
-from repro.core import (solvebakp, solvebakp_obs_sharded,
+from repro.core import (solvebakp, solvebakp_2d, solvebakp_obs_sharded,
                         solvebakp_rhs_sharded, solvebakp_vars_sharded)
 from repro.launch.mesh import make_debug_mesh
 
@@ -246,41 +246,50 @@ class TestRhsShardedApi:
         assert after - before <= 1  # one program serves every tolerance
 
 
-_MESH_SOLVERS = {"obs": solvebakp_obs_sharded, "rhs": solvebakp_rhs_sharded}
+_MESH_SOLVERS = {"obs": solvebakp_obs_sharded, "vars": solvebakp_vars_sharded,
+                 "2d": solvebakp_2d, "rhs": solvebakp_rhs_sharded}
 
 
 @pytest.mark.parametrize("warm", [False, True])
-@pytest.mark.parametrize("kind,k", [("obs", 1), ("obs", 4), ("rhs", 4)])
+@pytest.mark.parametrize("kind,k", [("obs", 1), ("obs", 4), ("vars", 1),
+                                    ("vars", 4), ("2d", 1), ("2d", 4),
+                                    ("rhs", 4)])
 @pytest.mark.parametrize("thr", [48, 128])
 @pytest.mark.parametrize("mode", ["gram", "jacobi"])
 def test_sharded_blocked_solve_matches_lstsq(mesh1, mode, thr, kind, k,
                                              warm):
     """Each column block is sliced out of the local shard (the last one
-    through the zero padding), for the block Gram, the column norms and
-    every block step: the mesh solve reaches the float64 solution for
-    every block width, RHS count, start and sharding."""
+    through the zero padding) for every block step, and the factors come
+    from the blocked view of the shard: the mesh solve reaches the float64
+    solution for every block width, RHS count, start and sharding."""
     solve = _MESH_SOLVERS[kind]
     x, y, ref, a0 = blocked_system(16, k)
-    res = solve(jnp.array(x), jnp.array(y), mesh1, thr=thr, max_iter=60,
-                mode=mode, a0=jnp.array(a0) if warm else None)
+    kw = dict(thr=thr, mode=mode, omega=1.0)
+    res = solve(jnp.array(x), jnp.array(y), mesh1, max_iter=60,
+                a0=jnp.array(a0) if warm else None, **kw)
     assert res.coef.shape == ref.shape
     np.testing.assert_allclose(np.array(res.coef), ref, rtol=0, atol=5e-6)
     np.testing.assert_allclose(np.array(res.residual),
                                y - x @ np.array(res.coef), rtol=0,
                                atol=2e-4)
     if warm:  # the start is honoured: the first sweep begins near ref
-        cold = solve(jnp.array(x), jnp.array(y), mesh1, thr=thr, max_iter=1,
-                     mode=mode)
+        cold = solve(jnp.array(x), jnp.array(y), mesh1, max_iter=1, **kw)
         assert float(res.history[0]) < float(cold.history[0])
 
 
-def test_obs_sharded_matches_single_device_sweep_for_sweep(mesh1):
-    """The mesh program's block Gram, formed block by block from in-place
-    slices, and the single-device program's give the same sweeps."""
-    x, y, _, _ = blocked_system(17, 1)
-    kw = dict(thr=128, max_iter=5, mode="gram")
+@pytest.mark.parametrize("warm", [False, True])
+@pytest.mark.parametrize("mode", ["gram", "jacobi"])
+@pytest.mark.parametrize("kind", ["obs", "vars", "2d", "rhs"])
+def test_obs_sharded_matches_single_device_sweep_for_sweep(mesh1, kind, mode,
+                                                           warm):
+    """The mesh program and the single-device program run one block-CD
+    body, factors formed one way: on a one-device mesh every sharding
+    gives the single-device sweeps."""
+    x, y, _, a0 = blocked_system(17, 4)
+    a0 = jnp.array(a0) if warm else None
+    kw = dict(thr=128, max_iter=5, mode=mode, omega=1.0, a0=a0)
     r1 = solvebakp(jnp.array(x), jnp.array(y), **kw)
-    r2 = solvebakp_obs_sharded(jnp.array(x), jnp.array(y), mesh1, **kw)
+    r2 = _MESH_SOLVERS[kind](jnp.array(x), jnp.array(y), mesh1, **kw)
     assert int(r1.n_sweeps) == int(r2.n_sweeps) == 5
     np.testing.assert_allclose(np.array(r2.history), np.array(r1.history),
                                rtol=1e-4)

@@ -60,8 +60,7 @@ class TestSolveBakP:
 
     def test_block_gram_cholesky_shapes(self, rng):
         x = rng.normal(size=(100, 32)).astype(np.float32)
-        xb = jnp.array(x).reshape(100, 4, 8)
-        chol = block_gram_cholesky(xb, ridge=1e-6)
+        chol = block_gram_cholesky(jnp.array(x), 8, 1e-6)
         assert chol.shape == (4, 8, 8)
         g = np.einsum("obt,obs->bts", x.reshape(100, 4, 8),
                       x.reshape(100, 4, 8)) + 1e-6 * np.eye(8)
@@ -132,8 +131,8 @@ def test_vmapped_batch_matches_lstsq(mode, warm):
                                           max_iter=60))
     args = [xs, ys, cns, jnp.zeros((2,), jnp.float32)]
     if mode == "gram":
-        args.append(jax.vmap(block_gram_cholesky, in_axes=(0, None))(
-            x_pad.reshape(2, xs.shape[1], -1, thr), 1e-6))
+        args.append(jax.vmap(lambda x: block_gram_cholesky(x, thr, 1e-6))(
+            x_pad))
     if warm:
         args.append(a0s)
     res = jax.jit(jax.vmap(one))(*args)

@@ -1,7 +1,7 @@
 """Compile rehearsal: the served path's Pallas kernels, at the sizes
-chip_smoke.py runs them, and the XLA gram solve at the tall and sharded
-deployments' sizes, compiled by the TPU compiler for described (not
-attached) v5e chips.
+chip_smoke.py runs them, and the XLA gram solve and its prepared block
+factors at the tall and sharded deployments' sizes, compiled by the TPU
+compiler for described (not attached) v5e chips.
 
 Interpret mode accepts what Mosaic refuses — unaligned slices, slices of
 loaded values, more VMEM than the scoped limit — so these compiles are
@@ -21,7 +21,8 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import solvebakp, solvebakp_obs_sharded
+from repro.core import (block_gram_cholesky, solvebakp,
+                        solvebakp_obs_sharded)
 from repro.kernels import (block_update, fused_fits, fused_solve,
                            score_features, solvebakp_persweep_kernel,
                            stream_fits, stream_solve)
@@ -139,11 +140,12 @@ def test_xla_gram_solve_reads_blocks_in_place(one_chip, k):
 def test_sharded_gram_solve_reads_blocks_in_place(topo):
     """The obs-sharded ``bakp_gram`` program at the sharded deployment's
     padded shape (4194304 x 1024 fp32 over the four chips of a v5e:2x2,
-    thr 128, k = 8, cold).  Each chip slices its column blocks out of its
-    local shard where they lie, for the block Gram and for every block
-    step: the per-chip scratch stays below one (obs_loc, thr) block.  The
-    program that cut each block from a per-call blocked copy of the shard
-    needed 4,832,871,424 B of scratch here."""
+    thr 128, k = 8, cold).  Each chip forms its block Grams by one einsum on
+    the blocked view of its local shard and slices each block step's
+    columns out of the shard where they lie: the per-chip scratch stays
+    below one (obs_loc, thr) block.  The program that cut each block from a
+    per-call blocked copy of the shard needed 4,832,871,424 B of scratch
+    here."""
     obs, nvars, thr, k = 1 << 22, 1024, 128, 8
     mesh = Mesh(np.array(topo.devices[:4]), ("data",))
 
@@ -160,6 +162,21 @@ def test_sharded_gram_solve_reads_blocks_in_place(topo):
     assert "all-reduce" in text           # the psums across the chips
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < (obs // 4) * thr * 4, temp
+
+
+def test_prepared_gram_factor_reads_blocks_in_place(one_chip):
+    """The factor program ``PreparedDesign.chol_for`` runs
+    (``block_gram_cholesky``) at the tall deployment's padded shape
+    (1048576 x 1024 fp32, thr 128): the block Grams come from one einsum on
+    the blocked view of x, so the scratch stays below one (obs, thr) block.
+    Forming each block's Gram from a slice in a ``lax.map`` needed
+    2,147,548,160 B here."""
+    obs, nvars, thr = 1 << 20, 1024, 128
+    x = jax.ShapeDtypeStruct((obs, nvars), F32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda x: block_gram_cholesky(x, thr, 1e-6)).lower(x).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < obs * thr * 4, temp
 
 
 def test_block_kernels_compile(one_chip):
