@@ -47,7 +47,7 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.solvebakp import block_cd, check_rhs, first_rhs
 from repro.core.types import SolveResult
@@ -67,6 +67,42 @@ _KINDS = {
     "rhs": lambda d, m: (P(None, None), P(None, d), P(None, d),
                          P(None, d), P(None, d), (), (), d),
 }
+
+# The serving placement kinds (``repro.serve.placement``) and the ``_KINDS``
+# entry each one's backend runs.
+_PLACEMENT_KINDS = {"obs_sharded": "obs", "rhs_sharded": "rhs",
+                    "mesh_2d": "2d"}
+
+
+def _kind_of(placement_kind: str) -> str:
+    if placement_kind not in _PLACEMENT_KINDS:
+        raise ValueError(f"unknown placement kind {placement_kind!r}")
+    return _PLACEMENT_KINDS[placement_kind]
+
+
+def placement_shardings(kind: str, smesh
+                        ) -> Tuple[NamedSharding, NamedSharding]:
+    """The ``NamedSharding``s a serving placement's program takes ``x`` and
+    its (obs, k) ``y`` in, read from ``_KINDS``: ``x`` by rows over the
+    data axes (obs_sharded), replicated (rhs_sharded) or by rows × columns
+    (mesh_2d); ``y`` by rows, or by right-hand sides (rhs_sharded).  The
+    residual comes back in ``y``'s (every kind's ``out_e`` is its
+    ``in_y``)."""
+    in_x, in_y = _KINDS[_kind_of(kind)](tuple(smesh.data_axes),
+                                        smesh.model_axis)[:2]
+    return NamedSharding(smesh.mesh, in_x), NamedSharding(smesh.mesh, in_y)
+
+
+def solvebakp_placed(kind: str, x, y, smesh, **kw) -> SolveResult:
+    """SolveBakP for a serving placement ``kind`` on the ``ServeMesh``
+    ``smesh``: the ``_KINDS`` entry the placement runs, over the mesh's
+    data axes (and its model axis for mesh_2d).  ``kw`` as for
+    ``solvebakp_obs_sharded`` (thr, max_iter, atol, rtol, omega, mode,
+    ridge, a0)."""
+    k = _kind_of(kind)
+    return _solve_sharded(
+        k, x, y, smesh.mesh, data_axes=smesh.data_axes,
+        model_axis=smesh.model_axis if k == "2d" else None, **kw)
 
 
 @functools.lru_cache(maxsize=128)

@@ -40,8 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.distributed import (solvebakp_2d, solvebakp_obs_sharded,
-                                    solvebakp_rhs_sharded)
+from repro.core.distributed import solvebakp_placed
 from repro.core.solvebak import solvebak
 from repro.core.solvebakf import solvebakf
 from repro.core.solvebakp import solvebakp
@@ -50,15 +49,9 @@ from repro.core.spec import (_ITER_FIELDS, MethodEntry, SolverSpec,
 from repro.core.types import SolveResult, fp32_matmuls
 from repro.obs import record_dispatch
 
-_SHARDED_BACKENDS = {
-    "obs_sharded": solvebakp_obs_sharded,
-    "rhs_sharded": solvebakp_rhs_sharded,
-}
-
-
 # --------------------------------------------------------------- BAK family
 def _bak_solve(p, y, spec: SolverSpec, *, a0=None, key=None, placement=None,
-               mesh=None):
+               mesh=None, donate=None):
     # Kernel-path relay: these solve bodies run eagerly per call (jit lives
     # inside the solvers), so recording here reports the route each solve
     # actually took; the vmap_one closures are jit-traced and must NOT
@@ -66,7 +59,7 @@ def _bak_solve(p, y, spec: SolverSpec, *, a0=None, key=None, placement=None,
     record_dispatch("xla", method="bak")
     return solvebak(p.x_pad, y, max_iter=spec.max_iter, atol=spec.atol,
                     rtol=spec.rtol, a0=a0, order=spec.order, key=key,
-                    cn=p.cn)
+                    cn=p.cn, donate=donate)
 
 
 def _bak_vmap_one(spec: SolverSpec):
@@ -89,26 +82,17 @@ def _bakp_solve(mode: str):
     method_name = "bakp" if mode == "jacobi" else "bakp_gram"
 
     def kernel(p, y, spec: SolverSpec, *, a0=None, key=None, placement=None,
-               mesh=None):
+               mesh=None, donate=None):
         if placement is not None and placement.sharded:
             if mesh is None:
                 raise ValueError(
                     f"placement {placement.kind!r} needs a ServeMesh")
             record_dispatch("sharded", method=method_name)
-            x_dev = p.x_for_placement(placement, mesh)
-            kw = dict(thr=spec.thr, max_iter=spec.max_iter, atol=spec.atol,
-                      rtol=spec.rtol, omega=spec.omega, mode=mode,
-                      ridge=spec.ridge, a0=a0)
-            if placement.kind == "mesh_2d":
-                return solvebakp_2d(x_dev, y, mesh.mesh,
-                                    data_axes=mesh.data_axes,
-                                    model_axis=mesh.model_axis, **kw)
-            backend = _SHARDED_BACKENDS.get(placement.kind)
-            if backend is None:
-                raise ValueError(
-                    f"unknown placement kind {placement.kind!r}")
-            return backend(x_dev, y, mesh.mesh, data_axes=mesh.data_axes,
-                           **kw)
+            return solvebakp_placed(
+                placement.kind, p.x_for_placement(placement, mesh), y, mesh,
+                thr=spec.thr, max_iter=spec.max_iter, atol=spec.atol,
+                rtol=spec.rtol, omega=spec.omega, mode=mode,
+                ridge=spec.ridge, a0=a0)
         record_dispatch("xla", method=method_name)
         return solvebakp(
             p.x_pad, y, thr=spec.thr, max_iter=spec.max_iter, atol=spec.atol,
@@ -116,7 +100,7 @@ def _bakp_solve(mode: str):
             cn=p.cn_for_thr(spec.thr),
             chol=(p.chol_for(spec.thr, spec.ridge) if mode == "gram"
                   else None),
-            a0=a0)
+            a0=a0, donate=donate)
     return kernel
 
 
@@ -152,7 +136,7 @@ def _prep_bakp_gram(p, spec: SolverSpec):
 
 # ------------------------------------------------- fused megakernel methods
 def _refine_fp32(p, y, spec: SolverSpec, lp: SolveResult, *, variant: str,
-                 nrhs: int) -> SolveResult:
+                 nrhs: int, donate=None) -> SolveResult:
     """fp32 polish for ``precision="bf16_fp32acc"`` (iterative refinement).
 
     Starts from the low-precision solution: the kernel entry's shared
@@ -175,7 +159,7 @@ def _refine_fp32(p, y, spec: SolverSpec, lp: SolveResult, *, variant: str,
     kw = dict(inv_cn=p.inv_cn_for(block), a0=lp.coef, block=block,
               max_iter=spec.refine_sweeps, atol=spec.atol, rtol=spec.rtol,
               omega=spec.omega if variant == "bakp" else 1.0,
-              variant=variant)
+              variant=variant, donate=donate)
     if fused_fits(x_t.shape[0], obs_p, nrhs, x_t.dtype.itemsize,
                   max_iter=spec.refine_sweeps):
         pol = fused_solve(x_t, y, **kw)
@@ -211,7 +195,7 @@ def _fused_method(variant: str):
     ``"bf16_fp32acc"`` appends the ``_refine_fp32`` polish.
     """
     def kernel(p, y, spec: SolverSpec, *, a0=None, key=None, placement=None,
-               mesh=None):
+               mesh=None, donate=None):
         # Imported at call time: repro.kernels itself imports repro.core
         # (types), so a module-level import here would make the package
         # import order matter (kernels-first would hit a half-initialised
@@ -238,11 +222,11 @@ def _fused_method(variant: str):
             if variant == "bak":
                 return solvebak(p.x_pad, y, max_iter=spec.max_iter,
                                 atol=spec.atol, rtol=spec.rtol, a0=a0,
-                                cn=p.cn)
+                                cn=p.cn, donate=donate)
             return solvebakp(p.x_pad, y, thr=block, max_iter=spec.max_iter,
                              atol=spec.atol, rtol=spec.rtol,
                              omega=spec.omega, mode="jacobi",
-                             cn=p.cn_for_thr(block), a0=a0)
+                             cn=p.cn_for_thr(block), a0=a0, donate=donate)
         if a0 is not None and vars_pb != vars_p:
             # Pad with the operand's own library: a host a0 must STAY host
             # (numpy) or the solver entry's auto-donation — the flush
@@ -252,10 +236,11 @@ def _fused_method(variant: str):
             a0 = xp.pad(xp.asarray(a0, jnp.float32),
                         ((0, vars_pb - vars_p),) + ((0, 0),) * (a0.ndim - 1))
         x_t = p.x_bf16_for(block) if lowp else p.x_t_for(block)
+        # The polish reads y again, so only its own solve may consume it.
         kw = dict(inv_cn=p.inv_cn_for(block), a0=a0, block=block,
                   max_iter=spec.max_iter, atol=spec.atol, rtol=spec.rtol,
                   omega=spec.omega if variant == "bakp" else 1.0,
-                  variant=variant)
+                  variant=variant, donate=None if polish else donate)
         if fits:
             record_dispatch("fused", method=f"{variant}_fused")
             res = fused_solve(x_t, y, **kw)
@@ -267,7 +252,8 @@ def _fused_method(variant: str):
                             reason="vmem")
             res = solvebakp_persweep_kernel(x_t, y, **kw)
         if polish:
-            res = _refine_fp32(p, y, spec, res, variant=variant, nrhs=nrhs)
+            res = _refine_fp32(p, y, spec, res, variant=variant, nrhs=nrhs,
+                               donate=donate)
         if vars_pb != vars_p:
             res = res._replace(coef=res.coef[:vars_p])
         return res
@@ -283,7 +269,7 @@ def _prep_fused(p, spec: SolverSpec):
 
 # ------------------------------------------------- streaming out-of-core
 def _stream_solve_method(p, y, spec: SolverSpec, *, a0=None, key=None,
-                         placement=None, mesh=None):
+                         placement=None, mesh=None, donate=None):
     """Algorithm 2 with X streamed rather than VMEM-resident.
 
     Resident designs run the double-buffered Pallas kernel
@@ -311,7 +297,8 @@ def _stream_solve_method(p, y, spec: SolverSpec, *, a0=None, key=None,
         record_dispatch("xla", method="bakp_stream", reason="max_iter")
         return solvebakp(p.x_pad, y, thr=block, max_iter=spec.max_iter,
                          atol=spec.atol, rtol=spec.rtol, omega=spec.omega,
-                         mode="jacobi", cn=p.cn_for_thr(block), a0=a0)
+                         mode="jacobi", cn=p.cn_for_thr(block), a0=a0,
+                         donate=donate)
     if a0 is not None and vars_pb != vars_p:
         xp = jnp if isinstance(a0, jax.Array) else np
         a0 = xp.pad(xp.asarray(a0, jnp.float32),
@@ -328,12 +315,13 @@ def _stream_solve_method(p, y, spec: SolverSpec, *, a0=None, key=None,
         if stream_fits(vars_pb, obs_p, nrhs, itemsize, block=block,
                        max_iter=spec.max_iter):
             record_dispatch("stream", method="bakp_stream")
-            res = stream_solve(x_t, y, **kw)
+            res = stream_solve(x_t, y, donate=donate, **kw)
         else:
             # Even the two-tile scratch is over budget (huge obs): the
             # per-sweep stream shares the bounded-VMEM property.
             record_dispatch("persweep", method="bakp_stream", reason="vmem")
-            res = solvebakp_persweep_kernel(x_t, y, variant="bakp", **kw)
+            res = solvebakp_persweep_kernel(x_t, y, variant="bakp",
+                                            donate=donate, **kw)
     if vars_pb != vars_p:
         res = res._replace(coef=res.coef[:vars_p])
     return res
@@ -349,7 +337,7 @@ def _prep_stream(p, spec: SolverSpec):
 
 # ---------------------------------------------------- greedy selection (A3)
 def _bakf_solve(p, y, spec: SolverSpec, *, a0=None, key=None, placement=None,
-                mesh=None):
+                mesh=None, donate=None):
     """Algorithm 3 run to full selection as a solver: greedily order every
     column by SSE reduction, refitting after each pick.  The final refit
     over all columns is an exact-block CD solve, so the solution matches
@@ -394,13 +382,13 @@ def _normal_kernel(x, y, ridge, max_iter: int) -> SolveResult:
 
 
 def _lstsq_solve(p, y, spec: SolverSpec, *, a0=None, key=None, placement=None,
-                 mesh=None):
+                 mesh=None, donate=None):
     record_dispatch("xla", method="lstsq")
     return _lstsq_kernel(p.x_pad, y, spec.max_iter)
 
 
 def _normal_solve(p, y, spec: SolverSpec, *, a0=None, key=None,
-                  placement=None, mesh=None):
+                  placement=None, mesh=None, donate=None):
     record_dispatch("xla", method="normal")
     return _normal_kernel(p.x_pad, y, jnp.float32(spec.ridge), spec.max_iter)
 

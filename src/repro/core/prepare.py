@@ -50,7 +50,9 @@ its compiled program without re-touching the registry.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
+import inspect
 import threading
 from collections import OrderedDict
 from dataclasses import dataclass, field
@@ -60,6 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.distributed import placement_shardings
 from repro.core.spec import (SolverSpec, UnsupportedSpecError,
                              ensure_precision_supported, solver_method,
                              streaming_methods)
@@ -67,20 +70,13 @@ from repro.core.types import (SolveResult, column_norms_sq, safe_inv,
                               warm_retention_ok)
 
 
-def placement_sharding(placement, smesh):
-    """The ``NamedSharding`` a sharded placement's backend takes ``x`` in:
-    rows over the data axes (obs_sharded), replicated (rhs_sharded — the
-    devices share x), or rows × columns (mesh_2d)."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    if placement.kind == "obs_sharded":
-        spec = P(smesh.data_axes, None)
-    elif placement.kind == "rhs_sharded":
-        spec = P(None, None)
-    elif placement.kind == "mesh_2d":
-        spec = P(smesh.data_axes, smesh.model_axis)
-    else:
-        raise ValueError(f"unknown placement kind {placement.kind!r}")
-    return NamedSharding(smesh.mesh, spec)
+@functools.lru_cache(maxsize=None)
+def _takes_donate(kernel) -> bool:
+    """Whether a method's solve kernel takes ``donate``: a kernel written
+    to the signature without it (a third-party method) is called without
+    it, and its operands are simply not consumed."""
+    return any(p.name == "donate" or p.kind is p.VAR_KEYWORD
+               for p in inspect.signature(kernel).parameters.values())
 
 
 def design_fingerprint(x, *, _prefix: str = "d") -> str:
@@ -295,7 +291,7 @@ class PreparedDesign:
             if placement not in self._sharded:
                 self._sharded[placement] = jax.device_put(
                     self._require_x("x_for_placement"),
-                    placement_sharding(placement, smesh))
+                    placement_shardings(placement.kind, smesh)[0])
             return self._sharded[placement]
 
     def warm_method_state(self, spec: SolverSpec) -> None:
@@ -364,6 +360,7 @@ class PreparedDesign:
         tenant_id: Optional[str] = None,
         placement=None,
         mesh=None,
+        donate: Optional[bool] = None,
     ) -> SolveResult:
         """Solve ``x @ a ≈ y`` against this prepared design.
 
@@ -383,6 +380,11 @@ class PreparedDesign:
             direct users of the handle too).
           placement / mesh: mesh-sharded execution (serving placement layer;
             ``mesh`` defaults to the one bound at ``prepare`` time).
+          donate: donate ``y``/``a0`` to the solver entry
+            (``types.donate_default``): True for device operands the caller
+            owns and will not reuse.  Passed to the method's kernel only
+            when set and the kernel takes it; the mesh-sharded programs
+            donate nothing.
 
         Returns:
           ``SolveResult`` in this design's (padded) shapes.
@@ -395,7 +397,8 @@ class PreparedDesign:
         # Normalise to an ndim-carrying array but keep HOST buffers host:
         # the solver entry points auto-donate fresh in-jit transfers of
         # numpy operands (types.donate_default), which is how the serving
-        # flush path sheds its steady-state HBM allocation.
+        # flush path sheds its steady-state HBM allocation (a coalesced
+        # group's device y comes with ``donate=True`` instead).
         if not hasattr(y, "ndim"):
             y = np.asarray(y, np.float32)
         entry = ensure_precision_supported(spec)
@@ -423,8 +426,10 @@ class PreparedDesign:
                 a0 = jnp.asarray(warm)
         if a0 is not None and not entry.iterative:
             a0 = None  # direct methods ignore warm starts (SolverSpec doc)
+        extra = ({"donate": donate}
+                 if donate is not None and _takes_donate(entry.solve) else {})
         res = entry.solve(self, y, spec, a0=a0, key=key,
-                          placement=placement, mesh=mesh)
+                          placement=placement, mesh=mesh, **extra)
         # A diverged solve's coefficients would poison the tenant's next
         # warm start (it would resume from the blown-up point); plain
         # budget exhaustion still retains — see warm_retention_ok.
@@ -463,7 +468,7 @@ def prepare(
     sharded = placement is not None and placement.sharded
     if sharded:
         x = jax.device_put(np.asarray(x, np.float32),
-                           placement_sharding(placement, mesh))
+                           placement_shardings(placement.kind, mesh)[0])
     else:
         x = jnp.asarray(x, jnp.float32)
     if x.ndim != 2:

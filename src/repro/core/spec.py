@@ -193,7 +193,10 @@ class MethodEntry:
     Attributes:
       name:      registry key (``SolverSpec.method``).
       solve:     kernel ``(prepared, y, spec, *, a0, key, placement, mesh)
-                 -> SolveResult`` consuming a ``PreparedDesign``.
+                 -> SolveResult`` consuming a ``PreparedDesign``; a kernel
+                 that also takes ``donate`` gets it when the caller of
+                 ``PreparedDesign.solve`` sets it (the serving engine does
+                 for coalesced groups).
       consumes:  SolverSpec fields that change this method's result —
                  drives ``SolverSpec.canonical()`` and therefore serving
                  batch grouping.

@@ -122,9 +122,10 @@ def donate_default(donate, *operands) -> bool:
         else can hold it.  A ``jax.Array`` operand is never auto-donated:
         the caller may reuse it (benchmarks re-solving one ``y``, parity
         loops), and a deleted-buffer crash is worse than a copy.  The
-        serving engine hands the solvers host buffers, so the flush path
-        donates; pass ``donate=True`` to force it for device operands you
-        own.
+        serving engine hands a single solve host buffers, so that solve
+        donates; a coalesced group's ``y`` is a device operand the engine
+        owns, and it passes ``donate=True``.  Pass that for any device
+        operand you own.
     """
     if donate is not None:
         return bool(donate)

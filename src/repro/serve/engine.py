@@ -89,8 +89,10 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
+from repro.core.distributed import placement_shardings
 from repro.core.prepare import PreparedDesign
 from repro.core.spec import SolverSpec, solver_method
 from repro.kernels.fused_solve import fused_fits
@@ -112,6 +114,30 @@ _log = logging.getLogger(__name__)
 # instead of a resident X copy that could never be admitted.
 _STREAM_REROUTE = frozenset(
     {"bak", "bakp", "bakp_gram", "bakp_fused", "bak_fused"})
+
+
+@functools.lru_cache(maxsize=32)
+def _transpose_to(sharding: Optional[NamedSharding]):
+    """A jitted 2-D transpose whose result lands in ``sharding`` (None:
+    on the operand's own device)."""
+    return jax.jit(jnp.transpose, out_shardings=sharding)
+
+
+def _transposed(sharding) -> Optional[NamedSharding]:
+    """The sharding of a 2-D mesh array's transpose that keeps every shard
+    on the device that holds it, so the transpose moves nothing between
+    devices; None for a single-device array."""
+    if not isinstance(sharding, NamedSharding):
+        return None
+    spec = tuple(sharding.spec) + (None,) * (2 - len(sharding.spec))
+    return NamedSharding(sharding.mesh, P(spec[1], spec[0]))
+
+
+def _request_rows(residual) -> np.ndarray:
+    """A group's (obs_p, k_pad) residual on the host as request-major
+    (k_pad, obs_p) rows, transposed on the device where each shard lies:
+    each request's residual is then one contiguous row."""
+    return np.asarray(_transpose_to(_transposed(residual.sharding))(residual))
 
 
 class _HostTime:
@@ -750,7 +776,7 @@ class SolverServeEngine:
         return atol * math.sqrt(n_real / n_padded)
 
     def _call_solver(self, spec: SolverSpec, entry: PreparedDesign, y_dev,
-                     atol: float, a0=None, placement=None):
+                     atol: float, a0=None, placement=None, donate=None):
         """One (possibly multi-RHS) solve on the prepared design.
 
         Everything dispatches through ``PreparedDesign.solve`` — the
@@ -761,6 +787,7 @@ class SolverServeEngine:
         block is D·thr wide).  ``a0`` is the bucket-padded warm start (or
         None for the cold program — a separate jit signature, so cold
         solves don't pay the warm path's extra residual matmul).
+        ``donate`` goes to ``PreparedDesign.solve`` as is.
         """
         eff = spec.replace(atol=atol)
         if placement is not None and placement.kind == "mesh_2d":
@@ -769,7 +796,7 @@ class SolverServeEngine:
                  and placement.sharded else "")
         with obs.span(f"solve/{where}{eff.method}"):
             return entry.solve(y_dev, a0, spec=eff, placement=placement,
-                               mesh=self.mesh)
+                               mesh=self.mesh, donate=donate)
 
     # ------------------------------------------------------- retry ladder
     @staticmethod
@@ -825,11 +852,11 @@ class SolverServeEngine:
             return False  # coalesced (obs, k) batch stays coalesced
         return True
 
-    def _attempt_solve(self, spec: SolverSpec, entry, y, atol: float, a0,
-                       placement, host: _HostTime, *,
+    def _attempt_solve(self, spec: SolverSpec, entry, y_for, atol: float,
+                       a0, placement, host: _HostTime, *,
                        deadline_at: Optional[float] = None,
                        rebuild=None, sse0: Optional[float] = None,
-                       need_multi: bool = False):
+                       need_multi: bool = False, donate=None):
         """One solve with the retry/degradation ladder wrapped around it.
 
         Runs ``_call_solver`` and retries on a raised exception or a
@@ -853,8 +880,12 @@ class SolverServeEngine:
         the last diverged result returns as-is (flagged so ``_strip``
         skips warm retention).
 
-        Each attempt's divergence check reads the result back to the host,
-        so it counts as fetch time (``host``), not solve time.
+        ``y_for(placement)`` gives each attempt's ``y`` for that attempt's
+        placement, so a rung that drops the mesh placement never receives
+        a mesh-sharded operand; with ``donate`` each attempt's solve may
+        consume it, so ``y_for`` makes a consumed one anew.  Each attempt's
+        divergence check reads the result back to the host, so it counts as
+        fetch time (``host``), not solve time.
 
         Returns ``(res, spec, entry, placement, retries, diverged,
         a0_used)`` — the rung that finally served, so the caller records
@@ -868,8 +899,9 @@ class SolverServeEngine:
             res = None
             try:
                 faults.maybe_raise("solver.raise", cur.method)
-                res = self._call_solver(cur, cur_entry, y, atol, a0=cur_a0,
-                                        placement=cur_place)
+                res = self._call_solver(cur, cur_entry, y_for(cur_place),
+                                        atol, a0=cur_a0, placement=cur_place,
+                                        donate=donate)
                 jax.block_until_ready(res.coef)
             except Exception as e:
                 exc = e
@@ -1051,11 +1083,16 @@ class SolverServeEngine:
         with host.build():
             spec = self.spec_for(req0)
             mentry = solver_method(spec.method)
-            ys = np.zeros((obs_p, k_pad), np.float32)
+            # Request-major: each target is one contiguous row, and only
+            # the padding is zeroed; the device transposes the group into
+            # the solvers' (obs_p, k_pad) layout (``_group_y``).
+            ys_t = np.empty((k_pad, obs_p), np.float32)
+            ys_t[k:] = 0.0
             sse0 = 0.0
             for c, idx in enumerate(idxs):
                 y = np.asarray(requests[idx].y, np.float32)
-                ys[: y.shape[0], c] = y
+                ys_t[c, : y.shape[0]] = y
+                ys_t[c, y.shape[0]:] = 0.0
                 sse0 += float(np.dot(y, y))
             if mentry.iterative:
                 a0s = [self._resolve_a0(requests[idx], entry) for idx in idxs]
@@ -1076,23 +1113,36 @@ class SolverServeEngine:
         if key is not None:
             rebuild = lambda: self._design_entry(  # noqa: E731
                 key, req0, bucket, placement)[0]
+        operands = {}
+
+        def y_for(place):
+            # One device operand per placement the ladder tries, made
+            # after t0: the transfer belongs to the solve stretch.  The
+            # solve consumes it (donated), so a retry makes it anew.
+            if place not in operands or operands[place].is_deleted():
+                operands[place] = self._group_y(ys_t, place)
+            return operands[place]
+
         t0 = obs.now()
-        # ys/a0_mat go in as HOST buffers: the solver entries donate their
-        # fresh in-jit transfers on accelerator backends (the steady-state
-        # HBM saving of the flush path — see types.donate_default).
-        res, fspec, fentry, fplace, retries, diverged, a0_used = \
-            self._attempt_solve(
-                spec, entry, ys, atol, a0_mat, placement, host,
-                deadline_at=min(deadlines) if deadlines else None,
-                rebuild=rebuild, sse0=sse0, need_multi=True)
+        try:
+            res, fspec, fentry, fplace, retries, diverged, a0_used = \
+                self._attempt_solve(
+                    spec, entry, y_for, atol, a0_mat, placement, host,
+                    deadline_at=min(deadlines) if deadlines else None,
+                    rebuild=rebuild, sse0=sse0, need_multi=True,
+                    donate=True)
+        finally:
+            # The solve is done with y: free it before the residual's
+            # transpose takes a buffer of the same size.
+            operands.clear()
         dt = obs.now() - t0 - host.fetch_s
         with host.fetch():
             path = self._record_solve(fspec, fplace, "multi_rhs", k, dt)
             coef = np.asarray(res.coef)
-            resid = np.asarray(res.residual)
+            resid_t = _request_rows(res.residual)
             for c, idx in enumerate(idxs):
                 results[idx] = self._strip(
-                    requests[idx], coef[:, c], resid[:, c], bucket=bucket,
+                    requests[idx], coef[:, c], resid_t[c], bucket=bucket,
                     kind="multi_rhs", group_size=k, latency=dt, hit=hit,
                     n_sweeps=res.n_sweeps, converged=res.converged,
                     entry=fentry,
@@ -1106,6 +1156,19 @@ class SolverServeEngine:
             self.stats.multi_rhs_requests += k
             if fplace is not None and fplace.sharded:
                 self.stats.sharded_solves += 1
+
+    def _group_y(self, ys_t: np.ndarray, placement) -> jax.Array:
+        """A group's request-major host targets ``ys_t`` (k_pad, obs_p) as
+        the (obs_p, k_pad) operand the solvers take, placed for
+        ``placement``: the rows go to the devices in the transpose of the
+        placement's ``y`` sharding, and each device transposes its own
+        slab.  A single-device placement transposes on the default device.
+        The transpose is exact, so the solve sees the same values."""
+        if placement is None or not placement.sharded:
+            return _transpose_to(None)(jnp.asarray(ys_t))
+        y_sharding = placement_shardings(placement.kind, self.mesh)[1]
+        return _transpose_to(y_sharding)(
+            jax.device_put(ys_t, _transposed(y_sharding)))
 
     def _solve_vmapped(self, requests, singles, bucket, results,
                        flush_time=None):
@@ -1246,10 +1309,12 @@ class SolverServeEngine:
             rebuild = lambda: self._design_entry(  # noqa: E731
                 key, req, bucket, placement)[0]
         t0 = obs.now()
-        # Host buffers in — see _solve_multi_rhs on donation.
+        # Host buffers in: the solver entries donate their fresh in-jit
+        # transfers on accelerator backends (see types.donate_default).
         res, fspec, fentry, fplace, retries, diverged, a0_used = \
-            self._attempt_solve(spec, entry, y_pad, atol, a0_pad, placement,
-                                host, deadline_at=req.deadline_at,
+            self._attempt_solve(spec, entry, lambda place: y_pad, atol,
+                                a0_pad, placement, host,
+                                deadline_at=req.deadline_at,
                                 rebuild=rebuild,
                                 sse0=float(np.dot(y_real, y_real)))
         dt = obs.now() - t0 - host.fetch_s

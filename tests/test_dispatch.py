@@ -351,11 +351,11 @@ class TestFlushExceptionSafety:
         eng = SolverServeEngine()
         real = eng._call_solver
 
-        def boom(spec, entry, y_dev, atol, a0=None, placement=None):
+        def boom(spec, entry, y_dev, atol, **kw):
             # The cached PreparedDesign's fingerprint is the design_key.
             if entry.fingerprint == "bad":
                 raise RuntimeError("injected solver failure")
-            return real(spec, entry, y_dev, atol, a0=a0, placement=placement)
+            return real(spec, entry, y_dev, atol, **kw)
 
         monkeypatch.setattr(eng, "_call_solver", boom)
         out = eng.serve([

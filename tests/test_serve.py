@@ -179,6 +179,104 @@ class TestEngine:
             assert res.sse == pytest.approx(
                 float(np.sum(res.residual ** 2)), rel=1e-5, abs=1e-8)
 
+    def test_multi_rhs_group_matches_row_major_solve_bitwise(self, rng):
+        """A single-device group of 3 (k_pad 4) is built request-major and
+        transposed on the device: each answer equals, to the bit, a direct
+        ``PreparedDesign.solve`` on the row-major (obs_p, 4) numpy ``y``,
+        and each residual is a contiguous row."""
+        x = rng.normal(size=(300, 24)).astype(np.float32)
+        reqs = [SolveRequest(x=x, y=x @ rng.normal(size=(24,)).astype(
+                    np.float32), method="bakp_gram", thr=8, max_iter=60,
+                    rtol=1e-10, design_key="rows") for _ in range(3)]
+        eng = SolverServeEngine()
+        served = eng.serve(reqs)
+        assert [r.batch_kind for r in served] == ["multi_rhs"] * 3
+        entry = eng.cache.get("rows", record_stats=False)
+        y_rows = np.zeros((512, 4), np.float32)
+        for c, req in enumerate(reqs):
+            y_rows[:300, c] = req.y
+        direct = entry.solve(y_rows, spec=eng.spec_for(reqs[0]))
+        for c, r in enumerate(served):
+            assert np.array_equal(r.coef, np.asarray(direct.coef)[:24, c])
+            assert np.array_equal(r.residual,
+                                  np.asarray(direct.residual)[:300, c])
+            assert r.residual.flags.c_contiguous
+            assert r.residual.shape == (300,)
+        eng.shutdown()
+
+    def test_multi_rhs_padding_is_zeroed_after_a_larger_group(self, rng):
+        """Consecutive groups of 4 then 3: the second group's padding
+        right-hand side and rows reach the solver zeroed, its device ``y``
+        is donated to the solve (consumed), and its answers equal a fresh
+        engine's to the bit."""
+        x = rng.normal(size=(300, 24)).astype(np.float32)
+
+        def group(n):
+            return [SolveRequest(x=x, y=x @ rng.normal(size=(24,)).astype(
+                np.float32), method="bakp_gram", thr=8, max_iter=60,
+                rtol=1e-10, design_key="rows") for _ in range(n)]
+        first, second = group(4), group(3)
+        eng = SolverServeEngine()
+        seen, operands = [], []
+        call_solver = eng._call_solver
+
+        def recording(spec, entry, y_dev, atol, a0=None, placement=None,
+                      donate=None):
+            seen.append((np.array(y_dev), donate))
+            operands.append(y_dev)
+            return call_solver(spec, entry, y_dev, atol, a0=a0,
+                               placement=placement, donate=donate)
+        eng._call_solver = recording
+        eng.serve(first)
+        later = eng.serve(second)
+        (y1, donate1), (y2, donate2) = seen
+        assert donate1 is donate2 is True
+        assert all(op.is_deleted() for op in operands)
+        assert y1.shape == y2.shape == (512, 4)
+        assert np.array_equal(y2[:300, :3],
+                              np.stack([r.y for r in second], axis=1))
+        assert not y2[300:].any() and not y2[:, 3].any()
+        fresh = SolverServeEngine().serve(second)
+        for r, f in zip(later, fresh):
+            assert r.batch_kind == "multi_rhs" and r.group_size == 3
+            assert np.array_equal(r.coef, f.coef)
+            assert np.array_equal(r.residual, f.residual)
+        eng.shutdown()
+
+    def test_multi_rhs_group_serves_a_method_without_donate(self, rng):
+        """A registered method whose kernel has no ``donate`` keyword still
+        serves a coalesced group: the engine's ``donate=True`` is not passed
+        to it, and its answers equal the method it wraps."""
+        import dataclasses
+
+        from repro.core import register_method, solver_method
+        from repro.core import spec as spec_mod
+
+        lstsq = solver_method("lstsq")
+
+        def plain(p, y, spec, *, a0=None, key=None, placement=None,
+                  mesh=None):
+            return lstsq.solve(p, y, spec, a0=a0, key=key,
+                               placement=placement, mesh=mesh)
+        register_method(dataclasses.replace(lstsq, name="lstsq_plain",
+                                            solve=plain))
+        try:
+            x = rng.normal(size=(300, 24)).astype(np.float32)
+            ys = [x @ rng.normal(size=(24,)).astype(np.float32)
+                  for _ in range(3)]
+
+            def group(method):
+                return SolverServeEngine().serve([
+                    SolveRequest(x=x, y=y, method=method, design_key="plain")
+                    for y in ys])
+            served, wrapped = group("lstsq_plain"), group("lstsq")
+            for r, w in zip(served, wrapped):
+                assert r.batch_kind == "multi_rhs" and r.group_size == 3
+                assert np.array_equal(r.coef, w.coef)
+                assert np.array_equal(r.residual, w.residual)
+        finally:
+            spec_mod._REGISTRY.pop("lstsq_plain", None)
+
     def test_results_in_submission_order(self, rng):
         eng = SolverServeEngine()
         reqs = []

@@ -109,26 +109,33 @@ def test_sharded_engine_parity_subprocess():
 
 
 # The served path of the obs-sharded deployment (``bench/configs/
-# sharded_4m.json``) at a tiny size: eight single-RHS ``bakp_gram``
-# requests, outstanding at once as eight closed-loop callers leave them,
-# through ``AsyncDispatcher`` on a four-device mesh engine with the
-# configuration's placement policy (its cell threshold lowered to this
-# shape).  8,192 x 300 pads to the bucket 8,192 x 512: four column blocks
-# of thr 128, each a psum of a (128, 8) partial over the four row shards.
-# Prints one JSON line for the tests below to judge.
+# sharded_4m.json``) at a tiny size: K single-RHS ``bakp_gram`` requests,
+# outstanding at once as K closed-loop callers leave them, through
+# ``AsyncDispatcher`` on a four-device mesh engine with the configuration's
+# placement policy (its cell threshold lowered to this shape).  OBS x 300
+# pads to the bucket 8,192 x 512: four column blocks of thr 128, each a
+# psum of a (128, k_pad) partial over the four row shards.  Then the same
+# group is solved directly on a row-major (8,192, k_pad) numpy ``y``, the
+# solvers' own layout, and served once more under a forced divergence,
+# whose fallback rung drops the mesh placement.  Prints one JSON line for
+# the tests below to judge.
 CLOSED8_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import json
+    import sys
     import threading
     import numpy as np
     from repro import obs
     from repro.core import SolverSpec
-    from repro.serve import (AsyncDispatcher, DispatchConfig, PlacementPolicy,
-                             ServeConfig, SolveRequest, SolverServeEngine,
-                             build_serve_mesh)
+    from repro.resilience.faults import installed
+    from repro.serve import (AsyncDispatcher, DispatchConfig,
+                             PlacementPolicy, ServeConfig, SolveRequest,
+                             SolverServeEngine, build_serve_mesh)
+    from repro.serve.placement import OBS_SHARDED
 
-    OBS, VARS, K = 8192, 300, 8
+    OBS, K = int(sys.argv[1]), int(sys.argv[2])
+    VARS, OBS_P, K_PAD = 300, 8192, 8
     rng = np.random.default_rng(15)
     x = rng.standard_normal((OBS, VARS), dtype=np.float32)
     a = rng.uniform(1.0, 2.0, (K, VARS)) * rng.choice([-1.0, 1.0], (K, VARS))
@@ -138,21 +145,28 @@ CLOSED8_SCRIPT = textwrap.dedent("""
                   / np.linalg.norm(noise, axis=1, keepdims=True)) * noise
     spec = SolverSpec(method="bakp_gram", rtol=1e-8, max_iter=50)
 
+    def request(i):
+        return SolveRequest(x=x, y=ys[i], spec=spec, design_key="sharded",
+                            request_id=f"c{i}")
+
     obs.set_enabled(True)
     obs.get_tracer().clear()
-    policy = PlacementPolicy(obs_shard_min_cells=OBS * 512, rhs_shard_min_k=32)
+    policy = PlacementPolicy(obs_shard_min_cells=OBS_P * 512,
+                             rhs_shard_min_k=32)
     mesh_eng = SolverServeEngine(ServeConfig(placement_policy=policy),
                                  mesh=build_serve_mesh("4"))
-    # max_batch = K fires the group once the eighth request joins it.
+    # max_batch = K fires the group once the last request joins it.
     cfg = DispatchConfig(max_batch=K, idle_timeout_s=5.0)
     results = [None] * K
+    order = []  # the callers in the order the dispatcher took them
+    submit = threading.Lock()
     start = threading.Barrier(K)
     with AsyncDispatcher(mesh_eng, cfg) as disp:
         def client(i):
             start.wait()
-            ticket = disp.submit(SolveRequest(
-                x=x, y=ys[i], spec=spec, design_key="sharded",
-                request_id=f"c{i}"))
+            with submit:
+                order.append(i)
+                ticket = disp.submit(request(i))
             results[i] = ticket.result(timeout=300)
         threads = [threading.Thread(target=client, args=(i,))
                    for i in range(K)]
@@ -163,6 +177,35 @@ CLOSED8_SCRIPT = textwrap.dedent("""
     mesh_spans = [s.name for s in obs.get_tracer().spans()
                   if s.name.startswith("solve/")]
 
+    # The same group on the row-major numpy y, column c the c-th caller.
+    entry = mesh_eng.cache.get("sharded", record_stats=False)
+    y_rows = np.zeros((OBS_P, K_PAD), np.float32)
+    for c, i in enumerate(order):
+        y_rows[:OBS, c] = ys[i]
+    direct = entry.solve(y_rows, spec=mesh_eng.spec_for(request(0)),
+                         placement=OBS_SHARDED, mesh=mesh_eng.mesh)
+    d_coef = np.asarray(direct.coef)
+    d_resid = np.asarray(direct.residual)
+    bitwise = [bool(np.array_equal(results[i].coef, d_coef[:VARS, c])
+                    and np.array_equal(results[i].residual, d_resid[:OBS, c]))
+               for c, i in enumerate(order)]
+
+    # A forced divergence: the fallback rung ("bakp") drops the placement;
+    # each attempt's y is recorded with the placement it went to.
+    attempts = []
+    call_solver = mesh_eng._call_solver
+
+    def recording(spec, entry, y_dev, atol, a0=None, placement=None,
+                  donate=None):
+        attempts.append([placement.kind if placement is not None else None,
+                         len(y_dev.sharding.device_set)])
+        return call_solver(spec, entry, y_dev, atol, a0=a0,
+                           placement=placement, donate=donate)
+    mesh_eng._call_solver = recording
+    with installed({"solver.diverge": {"count": 1}}):
+        ladder = mesh_eng.serve([request(i) for i in range(K)])
+    mesh_eng._call_solver = call_solver
+
     obs.get_tracer().clear()
     single = SolverServeEngine(ServeConfig()).serve([SolveRequest(
         x=x, y=ys[0], spec=spec, design_key="single", request_id="s0")])
@@ -172,21 +215,42 @@ CLOSED8_SCRIPT = textwrap.dedent("""
     x64 = x.astype(np.float64)
     y64 = ys.astype(np.float64)
     ref = np.linalg.lstsq(x64, y64.T, rcond=None)[0].T
-    coef = np.stack([np.asarray(r.coef, np.float64) for r in results])
-    resid = np.stack([np.asarray(r.residual, np.float64) for r in results])
-    coef_err = (np.linalg.norm(coef - ref, axis=1)
-                / np.linalg.norm(ref, axis=1))
-    resid_err = (np.linalg.norm(resid - (y64 - coef @ x64.T), axis=1)
-                 / np.linalg.norm(y64, axis=1))
+
+    def errors(served):
+        coef = np.stack([np.asarray(r.coef, np.float64) for r in served])
+        resid = np.stack([np.asarray(r.residual, np.float64)
+                          for r in served])
+        coef_err = (np.linalg.norm(coef - ref, axis=1)
+                    / np.linalg.norm(ref, axis=1))
+        resid_err = (np.linalg.norm(resid - (y64 - coef @ x64.T), axis=1)
+                     / np.linalg.norm(y64, axis=1))
+        return coef_err.tolist(), resid_err.tolist()
+
+    coef_err, resid_err = errors(results)
+    ladder_coef_err, ladder_resid_err = errors(ladder)
     print(json.dumps({
+        "k": K,
         "ok": [r.ok for r in results],
         "group_size": [r.group_size for r in results],
         "batch_kind": [r.batch_kind for r in results],
         "placement": [r.placement for r in results],
         "kernel_path": [r.telemetry.kernel_path for r in results],
         "retries": [r.retries for r in results],
-        "coef_rel_err": coef_err.tolist(),
-        "resid_rel_err": resid_err.tolist(),
+        "coef_rel_err": coef_err,
+        "resid_rel_err": resid_err,
+        "residual_shape": [list(r.residual.shape) for r in results],
+        "residual_contiguous": [bool(r.residual.flags.c_contiguous)
+                                for r in results],
+        "bitwise_equal_row_major": bitwise,
+        "direct_padding_max": float(max(np.abs(d_resid[OBS:]).max(initial=0),
+                                        np.abs(d_resid[:, K:]).max(initial=0),
+                                        np.abs(d_coef[:, K:]).max(initial=0))),
+        "ladder_ok": [r.ok for r in ladder],
+        "ladder_retries": [r.retries for r in ladder],
+        "ladder_placement": [r.placement for r in ladder],
+        "ladder_attempts": attempts,
+        "ladder_coef_rel_err": ladder_coef_err,
+        "ladder_resid_rel_err": ladder_resid_err,
         "mesh_spans": mesh_spans,
         "single_spans": single_spans,
         "single_placement": single[0].placement,
@@ -194,12 +258,18 @@ CLOSED8_SCRIPT = textwrap.dedent("""
 """)
 
 
-@pytest.fixture(scope="module")
-def closed8_run():
+@pytest.fixture(scope="module", params=[(8192, 8), (8000, 5)],
+                ids=["k8", "k5_padded"])
+def closed8_run(request):
+    """The script at (obs, k): k = 8 fills the group and the shards; k = 5
+    pads it to k_pad 8, and obs 8,000 leaves the last shard 192 padding
+    rows."""
+    obs_, k = request.param
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
-    p = subprocess.run([sys.executable, "-c", CLOSED8_SCRIPT],
+    p = subprocess.run([sys.executable, "-c", CLOSED8_SCRIPT, str(obs_),
+                        str(k)],
                        capture_output=True, text=True, env=env, timeout=600)
     assert p.returncode == 0, p.stdout + "\n" + p.stderr[-3000:]
     return json.loads(p.stdout.strip().splitlines()[-1])
@@ -207,12 +277,13 @@ def closed8_run():
 
 def test_closed8_group_is_one_obs_sharded_solve(closed8_run):
     r = closed8_run
+    k = r["k"]
     assert all(r["ok"]), r
-    assert r["group_size"] == [8] * 8
-    assert r["batch_kind"] == ["multi_rhs"] * 8
-    assert r["placement"] == ["obs_sharded"] * 8
-    assert r["kernel_path"] == ["sharded"] * 8
-    assert r["retries"] == [0] * 8
+    assert r["group_size"] == [k] * k
+    assert r["batch_kind"] == ["multi_rhs"] * k
+    assert r["placement"] == ["obs_sharded"] * k
+    assert r["kernel_path"] == ["sharded"] * k
+    assert r["retries"] == [0] * k
 
 
 def test_closed8_answers_match_float64_lstsq(closed8_run):
@@ -228,6 +299,41 @@ def test_closed8_answers_match_float64_lstsq(closed8_run):
     residual, against float64 ``y - x a`` of the served coefficients."""
     assert max(closed8_run["coef_rel_err"]) < 1e-6, closed8_run
     assert max(closed8_run["resid_rel_err"]) < 1e-6, closed8_run
+
+
+def test_closed8_residuals_are_contiguous_rows(closed8_run):
+    """Each served residual is one contiguous row of the request-major
+    host copy, cut to the request's own obs."""
+    r = closed8_run
+    obs_ = 8192 if r["k"] == 8 else 8000
+    assert r["residual_contiguous"] == [True] * r["k"]
+    assert r["residual_shape"] == [[obs_]] * r["k"]
+
+
+def test_closed8_matches_row_major_solve_bitwise(closed8_run):
+    """The request-major build and fetch only move bytes: every served
+    coefficient and residual equals, to the bit, a direct
+    ``PreparedDesign.solve`` of the same group on the row-major
+    (obs_p, k_pad) numpy ``y``.  That solve's padding rows and padding
+    right-hand sides come back exactly zero, and no answer reads them."""
+    r = closed8_run
+    assert r["bitwise_equal_row_major"] == [True] * r["k"]
+    assert r["direct_padding_max"] == 0.0
+
+
+def test_closed8_ladder_remakes_y_for_the_fallback_rung(closed8_run):
+    """Under a forced divergence the fallback rung ("bakp") drops the mesh
+    placement; its ``y`` is made anew on one device (the first attempt's
+    lies on all four), and every request is still answered within the
+    tolerances of ``test_closed8_answers_match_float64_lstsq``."""
+    r = closed8_run
+    k = r["k"]
+    assert r["ladder_attempts"] == [["obs_sharded", 4], [None, 1]]
+    assert r["ladder_ok"] == [True] * k
+    assert r["ladder_retries"] == [1] * k
+    assert r["ladder_placement"] == ["single"] * k
+    assert max(r["ladder_coef_rel_err"]) < 1e-6, r
+    assert max(r["ladder_resid_rel_err"]) < 1e-6, r
 
 
 def test_solve_span_is_named_by_placement(closed8_run):

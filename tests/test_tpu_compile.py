@@ -164,6 +164,37 @@ def test_sharded_gram_solve_reads_blocks_in_place(topo):
     assert temp < (obs // 4) * thr * 4, temp
 
 
+@pytest.mark.parametrize("y_spec,obs,k", [
+    (P("data", None), 1 << 22, 8),    # obs_sharded: rows over the chips
+    (P(None, "data"), 1 << 20, 32),   # rhs_sharded: RHS over the chips
+], ids=["obs_sharded", "rhs_sharded"])
+def test_sharded_group_transposes_stay_on_their_chips(topo, y_spec, obs, k):
+    """A coalesced group on the four chips of a v5e:2x2 crosses the host
+    request-major, as (k, obs) rows.  Its y arrives in the transpose of the
+    placement's y sharding, and the engine's transpose to the program's
+    (obs, k) layout moves nothing between the chips and needs no scratch;
+    so does the residual's transpose back to request-major rows, which
+    keeps each chip's shard where it lies (4194304 x 8 is the sharded
+    deployment's group)."""
+    from repro.serve.engine import _transpose_to, _transposed
+
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+    y_sharding = NamedSharding(mesh, y_spec)
+    rows = _transposed(y_sharding)
+    assert rows.spec == P(y_spec[1], y_spec[0])
+    for src, dst, shape in ((rows, y_sharding, (k, obs)),
+                            (y_sharding, rows, (obs, k))):
+        compiled = _transpose_to(dst).lower(
+            jax.ShapeDtypeStruct(shape, F32, sharding=src)).compile()
+        text = compiled.as_text()
+        for collective in ("all-gather", "all-to-all", "collective-permute",
+                           "all-reduce"):
+            assert collective not in text, (shape, collective)
+        memory = compiled.memory_analysis()
+        assert memory.temp_size_in_bytes == 0, shape
+        assert memory.output_size_in_bytes == obs * k * 4 // 4, shape
+
+
 def test_prepared_gram_factor_reads_blocks_in_place(one_chip):
     """The factor program ``PreparedDesign.chol_for`` runs
     (``block_gram_cholesky``) at the tall deployment's padded shape
